@@ -21,7 +21,8 @@ machine's cores, so an N-way mesh on an M < N core runner may not beat one
 device — the batch gate then rests on exact parity, and the routed gate on
 the router picking "single" with bit-identical results (same executable)
 plus a hard speedup floor that the 0.10x class can never pass.
-`validate_artifact.py` enforces all of it.
+`validate_artifact.py` enforces all of it. On a TPU the bench refuses to
+run: the child would need the chip this process holds.
 """
 from __future__ import annotations
 
@@ -32,6 +33,7 @@ import sys
 import textwrap
 
 from benchmarks.common import emit
+from repro import utils
 
 _CODE = textwrap.dedent("""
     import json, os, time
@@ -139,6 +141,7 @@ _CODE = textwrap.dedent("""
 
 
 def run(n: int = 768, p: int = 48, B: int = 8, reps: int = 3) -> dict:
+    utils.refuse_on_tpu("bench_dist_solve")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = "src"

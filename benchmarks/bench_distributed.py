@@ -1,7 +1,8 @@
 """Distributed SVEN scaling check (§Discussion's 'distributed systems' row):
 runs the shard_map gram + primal solve on a simulated 8-device host mesh in
 a subprocess (the bench process itself keeps the real single device) and
-reports correctness + timing vs the single-device path."""
+reports correctness + timing vs the single-device path. On a TPU it refuses
+to run: the child would need the chip this process holds."""
 from __future__ import annotations
 
 import os
@@ -10,6 +11,7 @@ import sys
 import textwrap
 
 from benchmarks.common import emit
+from repro import utils
 
 _CODE = textwrap.dedent("""
     import os, time
@@ -40,6 +42,7 @@ _CODE = textwrap.dedent("""
 
 
 def run():
+    utils.refuse_on_tpu("bench_distributed")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", _CODE], env=env, cwd=os.getcwd(),

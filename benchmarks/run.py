@@ -29,6 +29,9 @@ def main() -> None:
                     help="where to write the path/batch JSON artifact")
     args = ap.parse_args()
 
+    from repro import utils
+    utils.enable_compile_cache()
+
     from benchmarks import (bench_batch, bench_crossover, bench_cv,
                             bench_dist_solve, bench_distributed,
                             bench_kernels, bench_lm_smoke, bench_nggp,

@@ -220,14 +220,23 @@ def _ridge_l1(X: jax.Array, y: jax.Array, lambda2) -> jax.Array:
     For t >= this, the L1 constraint is slack so nu(t) = 0. Solved in the
     cheaper of the (p, p) primal or (n, n) dual normal equations; lambda2 is
     floored so the Lasso limit returns the min-norm least-squares point.
+    Both systems are SPD and are solved through their eigendecomposition:
+    the TPU compiler has no f64 LU (`jnp.linalg.solve`), and its f64
+    Cholesky does not compile inside `shard_map` (the fold-parallel CV and
+    the serving fan-out run this point solver there).
     """
     n, p = X.shape
     dtype = X.dtype
     lam = jnp.maximum(jnp.asarray(lambda2, dtype), 1e-8)
+
+    def spd_solve(A, b):
+        w, V = jnp.linalg.eigh(A)
+        return V @ ((V.T @ b) / w)
+
     if p <= n:
-        b = jnp.linalg.solve(X.T @ X + lam * jnp.eye(p, dtype=dtype), X.T @ y)
+        b = spd_solve(X.T @ X + lam * jnp.eye(p, dtype=dtype), X.T @ y)
     else:
-        b = X.T @ jnp.linalg.solve(X @ X.T + lam * jnp.eye(n, dtype=dtype), y)
+        b = X.T @ spd_solve(X @ X.T + lam * jnp.eye(n, dtype=dtype), y)
     return jnp.sum(jnp.abs(b))
 
 

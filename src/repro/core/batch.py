@@ -73,13 +73,13 @@ def shard_map_lanes(mesh, axes: tuple, local, operands: tuple):
     over every mesh axis, the rest replicate; every output carries the
     leading batch axis.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     data_axes = tuple(mesh.axis_names)
     in_specs = tuple(P(data_axes) if ax == 0 else P() for ax in axes)
     return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=P(data_axes), check_rep=False)(*operands)
+                     out_specs=P(data_axes), check_vma=False)(*operands)
 
 
 def _sven_solve_one(config: SvenConfig):

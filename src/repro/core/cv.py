@@ -118,7 +118,7 @@ def _enet_cv_scan_sharded(Xtr, ytr, Xva, yva, lambda1s, lambda2,
     it is 1, which `_enet_cv_scan` special-cases to the plain un-vmapped
     loops: full device parallelism AND no masked-lockstep penalty.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = tuple(mesh.axis_names)
@@ -128,7 +128,7 @@ def _enet_cv_scan_sharded(Xtr, ytr, Xva, yva, lambda1s, lambda2,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes), P(axes), P(axes), P(axes), P(), P()),
-                     out_specs=(P(None, axes),) * 3, check_rep=False)(
+                     out_specs=(P(None, axes),) * 3, check_vma=False)(
                          Xtr, ytr, Xva, yva, lambda1s, lambda2)
 
 

@@ -27,7 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.svm.primal_newton import solve_primal_newton
 
@@ -82,7 +82,7 @@ def distributed_gram(mesh: Mesh, X: jax.Array, y: jax.Array, t: float,
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes)),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(X, y)
 
 
@@ -115,7 +115,7 @@ def distributed_gram_rs(mesh: Mesh, X: jax.Array, y: jax.Array, t: float) -> jax
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes, None), P(axes)),
-                     out_specs=P(axes, None), check_rep=False)(X, y)
+                     out_specs=P(axes, None), check_vma=False)(X, y)
 
 
 def distributed_gram_rs_syrk(mesh: Mesh, X: jax.Array, y: jax.Array, t: float) -> jax.Array:
@@ -149,7 +149,7 @@ def distributed_gram_rs_syrk(mesh: Mesh, X: jax.Array, y: jax.Array, t: float) -
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes, None), P(axes)),
-                     out_specs=P(axes, None), check_rep=False)(X, y)
+                     out_specs=P(axes, None), check_vma=False)(X, y)
 
 
 def interleaved_labels(p: int, n_dev: int, dtype) -> jax.Array:
@@ -174,7 +174,7 @@ def distributed_gram_paper(mesh: Mesh, X: jax.Array, y: jax.Array, t: float) -> 
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes, None), P(axes)),
-                     out_specs=P(), check_rep=False)(X, y)
+                     out_specs=P(), check_vma=False)(X, y)
 
 
 def make_distributed_hessian_matvec(mesh: Mesh, X: jax.Array, y: jax.Array,
@@ -208,7 +208,7 @@ def make_distributed_hessian_matvec(mesh: Mesh, X: jax.Array, y: jax.Array,
 
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(None, axes), P(), P(), P(), P()),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
 
     def hess_matvec(v, act, C_traced=None):
         C_op = C if C_traced is None else C_traced
@@ -287,7 +287,7 @@ def sharded_stats(X, y, t, *, mesh: Mesh):
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes, None), P(axes), P()),
-                     out_specs=(P(), P(), P()), check_rep=False)(
+                     out_specs=(P(), P(), P()), check_vma=False)(
                          X, y, jnp.asarray(t, X.dtype))
 
 
@@ -334,7 +334,7 @@ def sharded_hinge_stats(mesh: Mesh, X: jax.Array, y: jax.Array, t,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes, None), P(axes), P(), P(), P()),
-                     out_specs=(P(), P(), P(), P()), check_rep=False)(
+                     out_specs=(P(), P(), P(), P()), check_vma=False)(
                          X, y, jnp.asarray(t, dtype), jnp.asarray(C, dtype), w)
 
 
@@ -375,7 +375,7 @@ def _sven_sharded_primal(mesh: Mesh, X, y, t, C, warm_w, config):
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes, None), P(axes), P(), P(), P()),
-                     out_specs=(P(), P(), P(), P(), P()), check_rep=False)(
+                     out_specs=(P(), P(), P(), P(), P()), check_vma=False)(
                          X, y, jnp.asarray(t, dtype), jnp.asarray(C, dtype),
                          warm_w)
 

@@ -160,7 +160,8 @@ def _store_disk_calibration(cal: Calibration) -> None:
 def _gram_kernel_rate(flops_per_s: float) -> tuple[str, float]:
     """(resolved kernel backend, measured Gram-pass FLOPs/s) for this
     process's default platform. Compiled backends get a real measurement of
-    `kernels.shifted_gram`; interpret/ref backends keep the GEMM rate."""
+    `kernels.shifted_gram`, and a kernel that fails to compile raises;
+    interpret/ref backends keep the GEMM rate."""
     from repro.kernels import ops as kops
     from repro.kernels import registry
 
@@ -171,10 +172,7 @@ def _gram_kernel_rate(flops_per_s: float) -> tuple[str, float]:
     n, p = 2048, 256
     X = jnp.ones((n, p), jnp.float32)
     y = jnp.ones((n,), jnp.float32)
-    try:
-        t = _best_of(lambda: kops.shifted_gram(X, y, 1.0, backend=kb))
-    except Exception:  # noqa: BLE001 — no functional kernel: price as GEMM
-        return kb, flops_per_s
+    t = _best_of(lambda: kops.shifted_gram(X, y, 1.0, backend=kb))
     return kb, (2.0 * n * p * p) / max(t, 1e-9)
 
 
@@ -197,7 +195,7 @@ def calibrate(mesh: Optional[Mesh], *, force: bool = False) -> Calibration:
     reductions) — enough to resolve latency-vs-bandwidth without the
     calibration itself costing more than the solves it routes.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     ndev = mesh.size if mesh is not None else 1
     backend = jax.default_backend()
@@ -239,7 +237,7 @@ def calibrate(mesh: Optional[Mesh], *, force: bool = False) -> Calibration:
                            NamedSharding(mesh, P(axes, None)))
         f = jax.jit(shard_map(lambda v: jax.lax.psum(v, axes), mesh=mesh,
                               in_specs=P(axes, None), out_specs=P(),
-                              check_rep=False))
+                              check_vma=False))
         return _best_of(lambda: f(x))
 
     t_small = _psum_bench(16)                     # latency-bound
@@ -257,7 +255,7 @@ def calibrate(mesh: Optional[Mesh], *, force: bool = False) -> Calibration:
     Abs_ = jax.device_put(Ab, NamedSharding(mesh, P(axes, None, None)))
     fan = jax.jit(shard_map(lambda a: jnp.einsum("bij,bjk->bik", a, a),
                             mesh=mesh, in_specs=P(axes, None, None),
-                            out_specs=P(axes, None, None), check_rep=False))
+                            out_specs=P(axes, None, None), check_vma=False))
     t_fan = _best_of(lambda: fan(Abs_))
     fanout_speedup = max(t_seq / max(t_fan, 1e-9), 1e-3)
 
@@ -265,7 +263,7 @@ def calibrate(mesh: Optional[Mesh], *, force: bool = False) -> Calibration:
     # by one — prices the sharded path's replicated Newton solve, which on
     # an oversubscribed host-sim mesh is several times slower than it looks.
     rep = jax.jit(shard_map(lambda a: a @ a, mesh=mesh, in_specs=P(),
-                            out_specs=P(), check_rep=False))
+                            out_specs=P(), check_vma=False))
     t_rep = _best_of(lambda: rep(A))
     replicated_slowdown = max(t_rep / max(t_gemm, 1e-9), 1.0)
 

@@ -17,7 +17,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def sequential_reference(stage_fn, params, x):
@@ -71,4 +71,4 @@ def pipeline_apply(mesh: Mesh, stage_fn, params, x, *, axis: str = "pipe"):
     param_specs = jax.tree.map(
         lambda t: P(axis, *([None] * (t.ndim - 1))), params)
     return shard_map(local, mesh=mesh, in_specs=(param_specs, P()),
-                     out_specs=P(), check_rep=False)(params, x)
+                     out_specs=P(), check_vma=False)(params, x)

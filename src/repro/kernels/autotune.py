@@ -121,7 +121,7 @@ def resolve_tiles(op: str, backend: str, n: int, p: int,
     `backend` is a RESOLVED backend (registry.RESOLVED_BACKENDS). Source is
     one of "default" (static, no measurement possible), "memory", "disk",
     or "measured" (sweep ran here). `measure` overrides the timing probe —
-    the test seam.
+    the test seam. Raises RuntimeError when no candidate compiles.
     """
     body, interpret = registry.split_backend(backend)
     if op not in _CANDIDATES:
@@ -146,16 +146,23 @@ def resolve_tiles(op: str, backend: str, n: int, p: int,
 
     probe = measure or _measure_candidate
     seen: dict[tuple, float] = {}
+    errors: dict[tuple, Exception] = {}
     for cand in cands:
         tiles = _clamp(cand, op, nb, pb, body)
-        if tiles in seen:
+        if tiles in seen or tiles in errors:
             continue
         try:
             seen[tiles] = probe(op, body, tiles, nb, pb, dtype)
-        except Exception:  # noqa: BLE001 — a candidate the compiler rejects
-            continue       # (register pressure, shmem) just drops out
+        except Exception as e:  # noqa: BLE001 — a candidate the compiler
+            errors[tiles] = e   # rejects (register pressure, VMEM) drops out
     if not seen:
-        return default, "default"
+        # no tile compiles: the kernel itself is broken on this backend, and
+        # handing back the default would only defer the same failure
+        first = next(iter(errors.values()))
+        raise RuntimeError(
+            f"autotune: no {op} tile candidate compiled on backend "
+            f"{backend!r} at bucket {nb}x{pb}; tried {sorted(errors)}; "
+            f"first error: {first}") from first
     winner = min(seen, key=seen.get)
     _MEMORY[key] = winner
     utils.disk_cache_update("autotune", {key: list(winner)})

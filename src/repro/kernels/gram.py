@@ -18,8 +18,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# block index for "the first block": a Python 0 in an index map becomes an
+# i64 under jax_enable_x64, and Mosaic cannot lower an i64 block index
+_0 = np.int32(0)
 
 
 def _gram_kernel(xi_ref, xj_ref, y_ref, invt_ref, out_ref,
@@ -47,11 +52,14 @@ def _gram_kernel(xi_ref, xj_ref, y_ref, invt_ref, out_ref,
         xi, xj, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32, precision=prec)
     acc_a[...] += jax.lax.dot_general(
-        xi, yk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        xi, yk, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=prec)
     acc_b[...] += jax.lax.dot_general(
-        xj, yk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        xj, yk, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=prec)
     acc_c[...] += jax.lax.dot_general(
-        yk, yk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        yk, yk, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=prec)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -91,10 +99,10 @@ def gram_pallas_raw(
         in_specs=[
             pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk, 1), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+            pl.BlockSpec((bk, 1), lambda i, j, k: (k, _0)),
+            pl.BlockSpec((1, 1), lambda i, j, k: (_0, _0)),
         ],
-        out_specs=pl.BlockSpec((2, 2, bm, bn), lambda i, j, k: (0, 0, i, j)),
+        out_specs=pl.BlockSpec((2, 2, bm, bn), lambda i, j, k: (_0, _0, i, j)),
         out_shape=jax.ShapeDtypeStruct((2, 2, p, p), out_dtype),
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),

@@ -16,8 +16,20 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# block index for "the first block": a Python 0 in an index map becomes an
+# i64 under jax_enable_x64, and Mosaic cannot lower an i64 block index
+_0 = np.int32(0)
+#: MXU precision of every product: on a TPU the default runs f32 operands
+#: through one bf16 pass (about 1e-3 relative error in H v); the
+#: mat-vecs are memory-bound, so the full-precision passes cost nothing
+HIGHEST = jax.lax.Precision.HIGHEST
+#: per-tile scalar partials are written as one lane-aligned (8, 128) tile
+#: each: Mosaic rejects a (1, 1) block of a larger array
+PARTIAL_TILE = (8, 128)
 
 
 # ---------------------------------------------------------------- pass 1 ---
@@ -37,9 +49,11 @@ def _xtv_kernel(x_ref, v_ref, y_ref, at_ref, ab_ref, invt_ref,
     yk = y_ref[...].astype(jnp.float32)          # (bk, 1)
 
     acc_c[...] += jax.lax.dot_general(
-        xk, vk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        xk, vk, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=HIGHEST)
     acc_byv[...] += jax.lax.dot_general(
-        yk, vk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        yk, vk, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=HIGHEST)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -51,11 +65,13 @@ def _xtv_kernel(x_ref, v_ref, y_ref, at_ref, ab_ref, invt_ref,
         u_t = at * (c - byv)
         u_b = ab * (c + byv)
         d_ref[...] = (u_t + u_b).astype(d_ref.dtype)
-        e_ref[0, 0] = jnp.sum(u_b - u_t).astype(e_ref.dtype)
+        e_ref[...] = jnp.full(e_ref.shape, jnp.sum(u_b - u_t), e_ref.dtype)
 
 
 def hinge_xtv_raw(X, v2d, y2d, at2d, ab2d, invt, *, bp: int, bk: int,
                   interpret: bool = False):
+    """Returns (d (p, 1), e partials (p // bp, 8, 128)); every element of
+    partial tile i holds tile i's share of e."""
     n, p = X.shape
     assert n % bk == 0 and p % bp == 0
     grid = (p // bp, n // bk)
@@ -64,19 +80,19 @@ def hinge_xtv_raw(X, v2d, y2d, at2d, ab2d, invt, *, bp: int, bk: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bk, bp), lambda i, k: (k, i)),
-            pl.BlockSpec((bk, 1), lambda i, k: (k, 0)),
-            pl.BlockSpec((bk, 1), lambda i, k: (k, 0)),
-            pl.BlockSpec((bp, 1), lambda i, k: (i, 0)),
-            pl.BlockSpec((bp, 1), lambda i, k: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, k: (0, 0)),
+            pl.BlockSpec((bk, 1), lambda i, k: (k, _0)),
+            pl.BlockSpec((bk, 1), lambda i, k: (k, _0)),
+            pl.BlockSpec((bp, 1), lambda i, k: (i, _0)),
+            pl.BlockSpec((bp, 1), lambda i, k: (i, _0)),
+            pl.BlockSpec((1, 1), lambda i, k: (_0, _0)),
         ],
         out_specs=[
-            pl.BlockSpec((bp, 1), lambda i, k: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, k: (i, 0)),
+            pl.BlockSpec((bp, 1), lambda i, k: (i, _0)),
+            pl.BlockSpec((1, *PARTIAL_TILE), lambda i, k: (i, _0, _0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((p, 1), jnp.float32),
-            jax.ShapeDtypeStruct((p // bp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((p // bp, *PARTIAL_TILE), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bp, 1), jnp.float32),
@@ -99,7 +115,8 @@ def _xd_kernel(x_ref, d_ref, y_ref, v_ref, scal_ref, hv_ref, acc):
     xk = x_ref[...].astype(jnp.float32)          # (bn, bk)
     dk = d_ref[...].astype(jnp.float32)          # (bk, 1)
     acc[...] += jax.lax.dot_general(
-        xk, dk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        xk, dk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=HIGHEST)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -122,12 +139,12 @@ def hinge_xd_raw(X, d2d, y2d, v2d, scal, *, bn: int, bk: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((bk, 1), lambda i, k: (k, 0)),
-            pl.BlockSpec((bn, 1), lambda i, k: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, k: (i, 0)),
-            pl.BlockSpec((3, 1), lambda i, k: (0, 0)),
+            pl.BlockSpec((bk, 1), lambda i, k: (k, _0)),
+            pl.BlockSpec((bn, 1), lambda i, k: (i, _0)),
+            pl.BlockSpec((bn, 1), lambda i, k: (i, _0)),
+            pl.BlockSpec((3, 1), lambda i, k: (_0, _0)),
         ],
-        out_specs=pl.BlockSpec((bn, 1), lambda i, k: (i, 0)),
+        out_specs=pl.BlockSpec((bn, 1), lambda i, k: (i, _0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)],
         interpret=interpret,

@@ -98,6 +98,13 @@ def _next_mult(sz: int, base: int = 128) -> int:
     return max(m, 8)
 
 
+def _tile_partials(part: jax.Array) -> jax.Array:
+    """One scalar per grid tile from a kernel's per-tile partials: (G, 1)
+    from the Triton bodies, (G, 8, 128) lane-aligned tiles from the TPU
+    bodies (every element of a tile holds the same value)."""
+    return part.reshape(part.shape[0], -1)[:, 0]
+
+
 def _storage(Xp: jax.Array, precision: str) -> jax.Array:
     """bf16 keeps reduced-precision STORAGE (the Rgtsvm recipe — kernels
     accumulate f32 regardless); f32/tf32 leave the operand alone."""
@@ -236,7 +243,7 @@ def _hinge_hessian_matvec_jit(
     invt = (1.0 / jnp.asarray(t, jnp.float32)).reshape(1, 1)
     d2d, e_part = impl_xtv(Xp1, v2d, y2d, at2d, ab2d, invt,
                            bp=bp_, bk=bk1, interpret=interp)
-    e = jnp.sum(e_part)
+    e = jnp.sum(_tile_partials(e_part))
 
     bn_ = min(bn, _next_mult(n))
     bk2 = min(bk, _next_mult(p))
@@ -320,7 +327,7 @@ def _hinge_stats_jit(
     byw = (y @ w) / jnp.asarray(t, w.dtype)
     xi_pad = jnp.maximum(1.0 + byw, 0.0)   # padded cols: a=0 => both halves
     pad_loss = pad * jnp.asarray(C, jnp.float32) * 2.0 * xi_pad ** 2
-    loss = 0.5 * (w @ w) + jnp.sum(lp) - pad_loss
+    loss = 0.5 * (w @ w) + jnp.sum(_tile_partials(lp)) - pad_loss
     return margin, act, loss.astype(w.dtype), galpha
 
 
@@ -350,7 +357,7 @@ def sharded_shifted_gram(
     backend is unrelated to the kernel's actual placement, which is
     precisely why trace-time sniffing was a bug.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = tuple(mesh.axis_names)
@@ -366,5 +373,5 @@ def sharded_shifted_gram(
         return jax.lax.psum(Kb, axes)
 
     fn = shard_map(local, mesh=mesh, in_specs=(P(axes, None), P(axes), P()),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     return fn(X, y, jnp.asarray(t, X.dtype))

@@ -26,10 +26,13 @@ backend always wins. Traced call sites thread `SvenConfig.backend`, pinned
 pre-trace by `core.sven.resolve_backend`, so the choice is part of the
 static jit key.
 
-Ops without a body for the resolved platform fall back to "ref" via
-`lookup` — e.g. the hinge Hessian mat-vec has no Triton body (GEMV-shaped,
-memory-bound; cuBLAS under XLA is the honest choice), so "gpu" serves it
-from the oracle. `kernel_backends(op)` reports what is actually registered.
+`lookup` serves the "ref" oracle in place of a missing body only for the
+pairs in `REF_FALLBACKS`, the README "Backends & precision" matrix: the
+hinge Hessian mat-vec has no Triton body (GEMV-shaped, memory-bound;
+cuBLAS under XLA is the honest choice), so "gpu" serves it from the
+oracle. Any other missing body raises — in particular no op resolved to
+"tpu" silently runs the oracle. `kernel_backends(op)` reports what is
+actually registered.
 """
 from __future__ import annotations
 
@@ -52,6 +55,9 @@ _PLATFORM_DEFAULT = {
     "cpu": "tpu_interpret",
 }
 
+#: (op, body) pairs the README matrix serves from the "ref" oracle
+REF_FALLBACKS = frozenset({("hinge_xtv", "gpu"), ("hinge_xd", "gpu")})
+
 _REGISTRY: dict[tuple[str, str], Callable] = {}
 
 
@@ -70,9 +76,9 @@ def register(op: str, body: str):
 def lookup(op: str, backend: str) -> tuple[Callable, str, bool]:
     """Resolve (impl, body, interpret) for a RESOLVED backend.
 
-    Falls back to the "ref" body when the platform has no kernel for this
-    op — the fallback is part of the contract (README "Backends &
-    precision" matrix), not an error.
+    Falls back to the "ref" body only where `REF_FALLBACKS` (the README
+    "Backends & precision" matrix) says the platform has no kernel for this
+    op; any other missing body is a KeyError.
     """
     if backend not in RESOLVED_BACKENDS:
         raise ValueError(
@@ -82,7 +88,7 @@ def lookup(op: str, backend: str) -> tuple[Callable, str, bool]:
     body, interpret = split_backend(backend)
     if (op, body) in _REGISTRY:
         return _REGISTRY[(op, body)], body, interpret
-    if (op, "ref") in _REGISTRY:
+    if (op, body) in REF_FALLBACKS and (op, "ref") in _REGISTRY:
         return _REGISTRY[(op, "ref")], "ref", False
     raise KeyError(f"no kernel body registered for op {op!r} "
                    f"(backend {backend!r}); registered: {kernel_backends(op)}")

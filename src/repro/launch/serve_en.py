@@ -18,6 +18,7 @@ import jax
 jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 
+from repro import utils
 from repro.baselines import elastic_net_cd
 from repro.core import SvenConfig, enet, sven
 from repro.runtime import (CONSTRAINED, PENALIZED, ContinuousScheduler,
@@ -94,6 +95,7 @@ def run(argv=None):
     ap.add_argument("--events-out", type=str, default=None,
                     help="dump the structured event ring as JSONL on exit")
     args = ap.parse_args(argv)
+    utils.enable_compile_cache()
 
     if args.trace_out is not None:
         from repro.obs import enable_tracing

@@ -246,6 +246,7 @@ def main(argv=None) -> None:
 
     jax.config.update("jax_enable_x64", True)
 
+    from repro import utils
     from repro.core import reset_trace_counts, trace_counts
     from repro.runtime.scheduler import ContinuousScheduler
 
@@ -270,6 +271,7 @@ def main(argv=None) -> None:
     ap.add_argument("--events-out", default="",
                     help="write the structured event log (JSONL) here")
     args = ap.parse_args(argv)
+    utils.enable_compile_cache()
 
     if args.trace_out:
         from repro.obs.trace import enable_tracing

@@ -56,6 +56,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import utils
 from repro.obs import clock as obs_clock
 from repro.obs import events as obs_events
 from repro.obs.metrics import MetricsRegistry
@@ -208,6 +209,8 @@ class MultiHostCoordinator:
         if n_hosts < 1:
             raise ValueError(f"MultiHostCoordinator: n_hosts >= 1 required "
                              f"(got {n_hosts})")
+        if n_hosts > 1:
+            utils.refuse_on_tpu(f"MultiHostCoordinator(n_hosts={n_hosts})")
         self.n_hosts = n_hosts
         self.max_batch = max_batch
         self.min_n = min_n

@@ -788,7 +788,4 @@ class ContinuousScheduler:
 
 def _batch_ready(inf: _InFlight) -> bool:
     """True when a dispatched batch's arrays have landed (non-blocking)."""
-    try:
-        return bool(inf.beta.is_ready())
-    except AttributeError:     # older jax: no readiness probe, stay async
-        return False
+    return bool(inf.beta.is_ready())

@@ -1,5 +1,6 @@
-"""Shared small utilities: pytree helpers, dtype helpers, timing, and the
-tiny on-disk JSON cache used by kernel autotuning and routing calibration."""
+"""Shared small utilities: pytree helpers, dtype helpers, timing, the tiny
+on-disk JSON cache used by kernel autotuning and routing calibration, the
+one-process-per-TPU guard, and the persistent compile cache setup."""
 from __future__ import annotations
 
 import json
@@ -103,6 +104,38 @@ def disk_cache_update(kind: str, entries: dict) -> bool:
         return True
     except OSError:
         return False
+
+
+def refuse_on_tpu(what: str) -> None:
+    """Raise before `what` starts child processes that import JAX.
+
+    A TPU belongs to one process at a time: once this process has touched
+    JAX it holds the chip, and a child that needs the chip fails or hangs.
+    """
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} starts child processes that need JAX, and a TPU belongs "
+            f"to one process; run it on the CPU (JAX_PLATFORMS=cpu)")
+
+
+# -- persistent compilation cache ------------------------------------------
+
+#: where compiled executables persist when JAX_COMPILATION_CACHE_DIR is
+#: unset: a fixed directory inside the checkout (listed in .gitignore)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    `JAX_COMPILATION_CACHE_DIR` wins when set, and then no other directory
+    is configured. Otherwise the cache lives at `COMPILE_CACHE_DIR`. The
+    path is part of each entry's key, so it is never derived from a temp
+    name, a pid or the time: a directory that moves never hits.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(COMPILE_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def pretty_bytes(n: float) -> str:

@@ -132,3 +132,58 @@ def test_hinge_stats_property(n, p, seed):
     np.testing.assert_allclose(np.asarray(margin), np.asarray(m_ref), atol=1e-5 * scale)
     np.testing.assert_allclose(float(loss), float(l_ref), rtol=1e-4)
     np.testing.assert_allclose(np.asarray(galpha), np.asarray(g_ref), atol=1e-5 * scale)
+
+
+# -- no silent fallback on the TPU path -----------------------------------
+
+def test_resolve_tiles_raises_when_no_tpu_tile_compiles(tmp_path,
+                                                        monkeypatch):
+    from repro.kernels import autotune
+    monkeypatch.setenv("REPRO_CACHE_DIR", str(tmp_path))
+    autotune.clear_autotune_cache()
+    tried = []
+
+    def rejected(op, body, tiles, nb, pb, dtype):
+        tried.append(tiles)
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    with pytest.raises(RuntimeError, match="Mosaic failed") as err:
+        autotune.resolve_tiles("shifted_gram", "tpu", 4096, 256,
+                               measure=rejected)
+    assert "no shifted_gram tile candidate" in str(err.value)
+    assert len(tried) == len(set(tried)) == len(
+        autotune.GRAM_CANDIDATES["tpu"])
+    autotune.clear_autotune_cache()
+
+
+def test_registry_tpu_hinge_passes_serve_the_tpu_body():
+    from repro.kernels import hinge, registry
+    impl, body, interp = registry.lookup("hinge_xtv", "tpu")
+    assert impl is hinge.hinge_xtv_raw and body == "tpu" and not interp
+    impl, body, interp = registry.lookup("hinge_xd", "tpu_interpret")
+    assert impl is hinge.hinge_xd_raw and body == "tpu" and interp
+
+
+def test_registry_missing_body_raises_outside_the_matrix(monkeypatch):
+    from repro.kernels import registry
+    monkeypatch.setitem(registry._REGISTRY, ("probe_op", "ref"),
+                        lambda *a: None)
+    with pytest.raises(KeyError, match="probe_op"):
+        registry.lookup("probe_op", "tpu")
+    assert registry.lookup("probe_op", "ref")[1] == "ref"
+
+
+def test_gram_kernel_rate_lets_a_compile_error_through(monkeypatch):
+    from repro.core import routing
+    from repro.kernels import ops, registry
+
+    def broken(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(registry, "resolve_kernel_backend", lambda *a: "tpu")
+    monkeypatch.setattr(ops, "shifted_gram", broken)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        routing._gram_kernel_rate(1e9)
+    monkeypatch.setattr(registry, "resolve_kernel_backend",
+                        lambda *a: "tpu_interpret")
+    assert routing._gram_kernel_rate(1e9) == ("tpu_interpret", 1e9)
