@@ -1,0 +1,6 @@
+"""The benchmark's tests import `bench` and the program from the checkout."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
