@@ -48,6 +48,8 @@ import jax.numpy as jnp
 from repro.core import elastic_net as en
 from repro.core.screening import gap_safe_screen
 from repro.core.sven import SvenConfig, _bump_trace, _sven_core, resolve_backend
+from repro.obs.solve import PathRecord, default_solve_log
+from repro.obs.trace import get_tracer
 
 
 # ---------------------------------------------------------------------------
@@ -188,6 +190,25 @@ class EnetPoint(NamedTuple):
     gap: jax.Array        # duality gap at the screening warm point
     evals: jax.Array      # Illinois iterations spent (== SVEN solves)
     sven_iters: jax.Array # total inner solver iterations across evals
+    cg_steps: jax.Array   # CG iterations across evals (0 with FISTA: no CG)
+    stop: jax.Array       # int8 root-find stop cause, one of the STOP_* codes
+
+
+#: `EnetPoint.stop`: why the multiplier root-find ended. Only STOP_ROOT and
+#: STOP_NO_ROOT are certified points; the other two mark a point whose
+#: |nu - lambda1| is still above the stop tolerance.
+STOP_NO_ROOT = 0   # lambda1 >= lambda1_max: beta = 0, nothing to solve
+STOP_ROOT = 1      # |nu - lambda1| <= ftol
+STOP_BRACKET = 2   # bracket narrower than wtol, |nu - lambda1| > ftol
+STOP_EVALS = 3     # max_evals reached, |nu - lambda1| > ftol
+
+#: A bracket closed at |nu - lambda1| above this share of lambda1 is wrong,
+#: not merely unresolved, and is re-opened cold (`_enet_point`). A wrong-side
+#: endpoint left nu 0.7 lambda1 off on YearPredictionMSD-shaped paths; where
+#: emulated f64 (TPU v5e) cannot resolve nu to `f_rtol`, a bracket closes
+#: within 5e-5 lambda1 of the root, which a cold re-bracket would only
+#: polish at the cost of a dozen more solves.
+REBRACKET_RTOL = 1e-3
 
 
 class _Illinois(NamedTuple):
@@ -203,6 +224,8 @@ class _Illinois(NamedTuple):
     f: jax.Array          # nu - lambda1 at the last evaluated point
     evals: jax.Array
     iters: jax.Array
+    cg: jax.Array         # CG iterations summed over the evaluations
+    restarted: jax.Array  # bool: the bracket was re-opened cold once
 
 
 def cold_carry(X: jax.Array, y: jax.Array) -> EnetCarry:
@@ -247,6 +270,13 @@ def _enet_point(X: jax.Array, y: jax.Array, lambda1, lambda2,
     Pure traced function: lambda1/lambda2/warm state are operands, config is
     static — usable directly under jit, lax.scan (paths) and vmap (CV folds,
     serving batches). Returns (next_carry, EnetPoint).
+
+    A bracket that closes below `wtol` with nu still more than
+    `REBRACKET_RTOL` * lambda1 from lambda1 (an evaluation's inexact warm
+    solve put an endpoint on the wrong side of the root) is re-opened once,
+    cold: [0, t_ridge] with zero warm starts, inside the same while_loop,
+    so a vmapped lane never runs both branches of a cond. Every other point
+    runs exactly as before. `EnetPoint.stop` records how the loop ended.
     """
     n, p = X.shape
     dtype = X.dtype
@@ -254,16 +284,19 @@ def _enet_point(X: jax.Array, y: jax.Array, lambda1, lambda2,
     lambda2 = jnp.asarray(lambda2, dtype)
 
     if config.screen:
-        scr = gap_safe_screen(X, y, carry.beta, lambda1, lambda2)
+        with jax.named_scope("enet.screen"):
+            scr = gap_safe_screen(X, y, carry.beta, lambda1, lambda2)
         keep, gap = scr.keep, scr.gap
     else:
         keep = jnp.ones((p,), bool)
         gap = jnp.zeros((), dtype)
-    keepf = keep.astype(dtype)
-    Xm = X * keepf[None, :]
+    with jax.named_scope("enet.mask"):
+        keepf = keep.astype(dtype)
+        Xm = X * keepf[None, :]
 
-    l1max_m = 2.0 * jnp.max(jnp.abs(Xm.T @ y))
-    t_ridge = _ridge_l1(Xm, y, lambda2)
+    with jax.named_scope("enet.bracket"):
+        l1max_m = 2.0 * jnp.max(jnp.abs(Xm.T @ y))
+        t_ridge = _ridge_l1(Xm, y, lambda2)
     t_floor = config.t_floor_rel * t_ridge + jnp.asarray(1e-30, dtype)
     ftol = config.f_rtol * jnp.maximum(l1max_m, 1e-30)
     wtol = 1e-12 * t_ridge
@@ -273,10 +306,11 @@ def _enet_point(X: jax.Array, y: jax.Array, lambda1, lambda2,
     # nu(t_ridge) = 0; the warm (t, nu) from the previous (larger) lambda is a
     # tighter lower endpoint whenever it is on the correct side.
     f_warm = carry.nu - lambda1
+    f_cold = l1max_m - lambda1
     warm_ok = (f_warm > 0) & (carry.t > 0) & (carry.t < t_ridge)
     state0 = _Illinois(
         t_lo=jnp.where(warm_ok, carry.t, 0.0),
-        f_lo=jnp.where(warm_ok, f_warm, l1max_m - lambda1),
+        f_lo=jnp.where(warm_ok, f_warm, f_cold),
         t_hi=t_ridge,
         f_hi=-lambda1,
         side=jnp.zeros((), jnp.int32),
@@ -284,22 +318,41 @@ def _enet_point(X: jax.Array, y: jax.Array, lambda1, lambda2,
         alpha=carry.alpha * jnp.concatenate([keepf, keepf]),
         w=carry.w,
         nu=carry.nu,
-        f=jnp.where(warm_ok, f_warm, l1max_m - lambda1),
+        f=jnp.where(warm_ok, f_warm, f_cold),
         evals=jnp.zeros((), jnp.int32),
         iters=jnp.zeros((), jnp.int32),
+        cg=jnp.zeros((), jnp.int32),
+        restarted=jnp.zeros((), bool),
     )
 
+    def closed(s: _Illinois):
+        return s.t_hi - s.t_lo <= wtol
+
     def cond(s: _Illinois):
+        far = jnp.abs(s.f) > REBRACKET_RTOL * lambda1
         return ((s.evals < config.max_evals) & has_root
-                & (s.t_hi - s.t_lo > wtol) & (jnp.abs(s.f) > ftol))
+                & (jnp.abs(s.f) > ftol)
+                & (~closed(s) | (far & ~s.restarted)))
 
     def body(s: _Illinois):
+        # cond lets a closed bracket through only to re-open it (docstring)
+        cold = closed(s)
+        s = s._replace(
+            t_lo=jnp.where(cold, 0.0, s.t_lo),
+            f_lo=jnp.where(cold, f_cold, s.f_lo),
+            t_hi=jnp.where(cold, t_ridge, s.t_hi),
+            f_hi=jnp.where(cold, -lambda1, s.f_hi),
+            side=jnp.where(cold, 0, s.side),
+            alpha=jnp.where(cold, 0.0, s.alpha),
+            w=jnp.where(cold, 0.0, s.w),
+            restarted=s.restarted | cold)
         frac = s.f_lo / jnp.maximum(s.f_lo - s.f_hi, 1e-30)
         frac = jnp.clip(frac, 0.05, 0.95)   # never stall on an endpoint
         t_c = jnp.maximum(s.t_lo + frac * (s.t_hi - s.t_lo), t_floor)
         arrs = _sven_core(Xm, y, t_c, lambda2, s.alpha, s.w, config.solver)
-        g = en.smooth_grad(Xm, y, arrs.beta, lambda2)
-        nu_c = jnp.max(jnp.abs(g) * keepf)
+        with jax.named_scope("enet.grad"):
+            g = en.smooth_grad(Xm, y, arrs.beta, lambda2)
+            nu_c = jnp.max(jnp.abs(g) * keepf)
         f_c = nu_c - lambda1
         went_lo = f_c >= 0
         # Illinois: replacing the same endpoint twice halves the stale side's
@@ -313,9 +366,14 @@ def _enet_point(X: jax.Array, y: jax.Array, lambda1, lambda2,
         side = jnp.where(went_lo, 1, -1).astype(jnp.int32)
         return _Illinois(t_lo, f_lo, t_hi, f_hi, side, arrs.beta, arrs.alpha,
                          arrs.w, nu_c, f_c, s.evals + 1,
-                         s.iters + arrs.iters.astype(jnp.int32))
+                         s.iters + arrs.iters.astype(jnp.int32),
+                         s.cg + arrs.cg_steps.astype(jnp.int32), s.restarted)
 
     s = jax.lax.while_loop(cond, body, state0)
+    stop = jnp.where(~has_root, STOP_NO_ROOT,
+                     jnp.where(jnp.abs(s.f) <= ftol, STOP_ROOT,
+                               jnp.where(closed(s), STOP_BRACKET,
+                                         STOP_EVALS))).astype(jnp.int8)
 
     ok = has_root.astype(dtype)
     beta = s.beta * keepf * ok
@@ -323,10 +381,12 @@ def _enet_point(X: jax.Array, y: jax.Array, lambda1, lambda2,
     nu_out = jnp.where(has_root, s.nu, l1max_m)
     next_carry = EnetCarry(beta=beta, alpha=s.alpha * ok, w=s.w * ok,
                            t=t_out, nu=nu_out)
-    point = EnetPoint(beta=beta, t=t_out, nu=nu_out,
-                      kkt=en.kkt_violation(X, y, beta, lambda2),
+    with jax.named_scope("enet.kkt"):
+        kkt = en.kkt_violation(X, y, beta, lambda2)
+    point = EnetPoint(beta=beta, t=t_out, nu=nu_out, kkt=kkt,
                       keep=keep, n_kept=jnp.sum(keep), gap=gap,
-                      evals=s.evals, sven_iters=s.iters)
+                      evals=s.evals, sven_iters=s.iters, cg_steps=s.cg,
+                      stop=stop)
     return next_carry, point
 
 
@@ -416,49 +476,53 @@ def enet_batch(X, y, lambda1s, lambda2s,
     """
     from repro.core.batch import _maybe_shard_batch, batch_mesh
 
-    X = jnp.asarray(X)
-    dtype = X.dtype
-    y = jnp.asarray(y, dtype)
-    lambda1s = jnp.asarray(lambda1s, dtype)
-    lambda2s = jnp.asarray(lambda2s, dtype)
-    axes = (0 if X.ndim == 3 else None,
-            0 if y.ndim == 2 else None,
-            0 if lambda1s.ndim == 1 else None,
-            0 if lambda2s.ndim == 1 else None,
-            0 if warm is not None else None,
-            0 if warm is not None else None)
-    sizes = {op.shape[0] for op, ax in zip((X, y, lambda1s, lambda2s), axes)
-             if ax == 0}
-    if not sizes:
-        raise ValueError("enet_batch: no batched operand (use enet())")
-    if (warm is None) != (has_warm is None):
-        raise ValueError("enet_batch: warm and has_warm must be given together")
-    if has_warm is not None:
-        has_warm = jnp.asarray(has_warm, bool)
-        sizes.update(jnp.asarray(f).shape[0] for f in warm)
-        sizes.add(has_warm.shape[0])
-    if len(sizes) != 1:
-        raise ValueError(f"enet_batch: inconsistent batch sizes {sorted(sizes)}")
-    # route BEFORE placing (see sven_batch): the penalized lane runs the
-    # whole multiplier root-find, priced via form="penalized".
-    mesh = batch_mesh(next(iter(sizes)), X.shape[-2], X.shape[-1],
-                      form="penalized", route=route)
-    if mesh is not None:
-        X, y, lambda1s, lambda2s = (
-            _maybe_shard_batch(op, ax == 0)
-            for op, ax in zip((X, y, lambda1s, lambda2s), axes[:4]))
-        if warm is not None:
-            warm = EnetCarry(*(_maybe_shard_batch(jnp.asarray(f), True)
-                               for f in warm))
-            has_warm = _maybe_shard_batch(has_warm, True)
-    config = resolve_path_config(config, X, y)
-    if mesh is not None:
-        carry, points = _enet_batch_sharded_jit(X, y, lambda1s, lambda2s,
-                                                warm, has_warm, config, axes,
-                                                mesh)
-    else:
-        carry, points = _enet_batch_jit(X, y, lambda1s, lambda2s, warm,
-                                        has_warm, config, axes)
+    tracer = get_tracer()
+    with tracer.span("enet_path.prepare"):
+        X = jnp.asarray(X)
+        dtype = X.dtype
+        y = jnp.asarray(y, dtype)
+        lambda1s = jnp.asarray(lambda1s, dtype)
+        lambda2s = jnp.asarray(lambda2s, dtype)
+        axes = (0 if X.ndim == 3 else None,
+                0 if y.ndim == 2 else None,
+                0 if lambda1s.ndim == 1 else None,
+                0 if lambda2s.ndim == 1 else None,
+                0 if warm is not None else None,
+                0 if warm is not None else None)
+        sizes = {op.shape[0]
+                 for op, ax in zip((X, y, lambda1s, lambda2s), axes) if ax == 0}
+        if not sizes:
+            raise ValueError("enet_batch: no batched operand (use enet())")
+        if (warm is None) != (has_warm is None):
+            raise ValueError(
+                "enet_batch: warm and has_warm must be given together")
+        if has_warm is not None:
+            has_warm = jnp.asarray(has_warm, bool)
+            sizes.update(jnp.asarray(f).shape[0] for f in warm)
+            sizes.add(has_warm.shape[0])
+        if len(sizes) != 1:
+            raise ValueError(
+                f"enet_batch: inconsistent batch sizes {sorted(sizes)}")
+        # route BEFORE placing (see sven_batch): the penalized lane runs the
+        # whole multiplier root-find, priced via form="penalized".
+        mesh = batch_mesh(next(iter(sizes)), X.shape[-2], X.shape[-1],
+                          form="penalized", route=route)
+        if mesh is not None:
+            X, y, lambda1s, lambda2s = (
+                _maybe_shard_batch(op, ax == 0)
+                for op, ax in zip((X, y, lambda1s, lambda2s), axes[:4]))
+            if warm is not None:
+                warm = EnetCarry(*(_maybe_shard_batch(jnp.asarray(f), True)
+                                   for f in warm))
+                has_warm = _maybe_shard_batch(has_warm, True)
+        config = resolve_path_config(config, X, y)
+    with tracer.span("enet_path.dispatch"):
+        if mesh is not None:
+            carry, points = _enet_batch_sharded_jit(
+                X, y, lambda1s, lambda2s, warm, has_warm, config, axes, mesh)
+        else:
+            carry, points = _enet_batch_jit(X, y, lambda1s, lambda2s, warm,
+                                            has_warm, config, axes)
     return (points, carry) if return_carry else points
 
 
@@ -476,6 +540,8 @@ class EnetResult(NamedTuple):
     n_kept: jax.Array      # columns surviving the gap-safe screen
     evals: jax.Array       # SVEN solves spent on the multiplier root-find
     sven_iters: jax.Array
+    cg_steps: jax.Array    # CG iterations (0 with the FISTA solver)
+    stop: jax.Array        # root-find stop code (STOP_*)
 
 
 class EnetPath(NamedTuple):
@@ -489,24 +555,32 @@ class EnetPath(NamedTuple):
     n_kept: jax.Array      # (L,) columns surviving the screen
     evals: jax.Array       # (L,) SVEN solves per point
     sven_iters: jax.Array  # (L,)
+    cg_steps: jax.Array    # (L,) CG iterations (0 with the FISTA solver)
+    stop: jax.Array        # (L,) int8 root-find stop codes (STOP_*)
 
 
 def enet(X, y, lambda1, lambda2, *, standardize: bool = False,
          fit_intercept: bool = False,
          config: PathConfig = PathConfig()) -> EnetResult:
     """Solve one penalized Elastic Net (paper scaling) via the SVEN engine."""
-    X = jnp.asarray(X)
-    y = jnp.asarray(y, X.dtype)
-    Xs, ys, scaler = standardize_fit(X, y, standardize=standardize,
-                                     fit_intercept=fit_intercept)
-    config = resolve_path_config(config, Xs, ys)
-    _, pt = _enet_jit(Xs, ys, jnp.asarray(lambda1, X.dtype),
-                      jnp.asarray(lambda2, X.dtype), cold_carry(Xs, ys), config)
-    beta, intercept = unscale_coef(pt.beta, scaler)
+    tracer = get_tracer()
+    with tracer.span("enet_path.prepare"):
+        X = jnp.asarray(X)
+        y = jnp.asarray(y, X.dtype)
+        Xs, ys, scaler = standardize_fit(X, y, standardize=standardize,
+                                         fit_intercept=fit_intercept)
+        config = resolve_path_config(config, Xs, ys)
+    with tracer.span("enet_path.dispatch"):
+        _, pt = _enet_jit(Xs, ys, jnp.asarray(lambda1, X.dtype),
+                          jnp.asarray(lambda2, X.dtype), cold_carry(Xs, ys),
+                          config)
+    with tracer.span("enet_path.unscale"):
+        beta, intercept = unscale_coef(pt.beta, scaler)
     return EnetResult(beta=beta, intercept=intercept, lambda1=float(lambda1),
                       lambda2=float(lambda2), t=pt.t, nu=pt.nu,
                       n_kept=pt.n_kept, evals=pt.evals,
-                      sven_iters=pt.sven_iters)
+                      sven_iters=pt.sven_iters, cg_steps=pt.cg_steps,
+                      stop=pt.stop)
 
 
 def enet_path(X, y, *, lambda1s=None, n_lambdas: int = 40,
@@ -520,21 +594,35 @@ def enet_path(X, y, *, lambda1s=None, n_lambdas: int = 40,
     compiles to a single executable per (shape, grid length, config), so
     re-solving with new data or a rescaled grid never retraces
     (`trace_counts()["enet_path_scan"]`).
+
+    Besides the coefficients, every point reports its root-find
+    evaluations, inner iterations, CG steps (0 with the FISTA solver, which
+    runs no CG) and root-find stop code (`STOP_*`); each call appends them
+    as a `PathRecord` to `repro.obs.default_solve_log()`, without a sync.
     """
-    X = jnp.asarray(X)
-    y = jnp.asarray(y, X.dtype)
-    Xs, ys, scaler = standardize_fit(X, y, standardize=standardize,
-                                     fit_intercept=fit_intercept)
-    if lambda1s is None:
-        lambda1s = lambda_grid(Xs, ys, n_lambdas=n_lambdas, eps=eps)
-    lambda1s = jnp.asarray(lambda1s, X.dtype)
-    config = resolve_path_config(config, Xs, ys)
-    pts = _enet_path_scan(Xs, ys, lambda1s, jnp.asarray(lambda2, X.dtype), config)
-    betas, intercepts = unscale_coef(pts.beta, scaler)
+    tracer = get_tracer()
+    with tracer.span("enet_path.prepare"):
+        X = jnp.asarray(X)
+        y = jnp.asarray(y, X.dtype)
+        Xs, ys, scaler = standardize_fit(X, y, standardize=standardize,
+                                         fit_intercept=fit_intercept)
+        if lambda1s is None:
+            lambda1s = lambda_grid(Xs, ys, n_lambdas=n_lambdas, eps=eps)
+        lambda1s = jnp.asarray(lambda1s, X.dtype)
+        config = resolve_path_config(config, Xs, ys)
+    with tracer.span("enet_path.dispatch"):
+        pts = _enet_path_scan(Xs, ys, lambda1s, jnp.asarray(lambda2, X.dtype),
+                              config)
+    default_solve_log().add(PathRecord(evals=pts.evals,
+                                       sven_iters=pts.sven_iters,
+                                       cg_steps=pts.cg_steps, stop=pts.stop))
+    with tracer.span("enet_path.unscale"):
+        betas, intercepts = unscale_coef(pts.beta, scaler)
     return EnetPath(lambda1s=lambda1s, lambda2=float(lambda2), betas=betas,
                     intercepts=intercepts, ts=pts.t, nus=pts.nu, kkts=pts.kkt,
                     n_kept=pts.n_kept, evals=pts.evals,
-                    sven_iters=pts.sven_iters)
+                    sven_iters=pts.sven_iters, cg_steps=pts.cg_steps,
+                    stop=pts.stop)
 
 
 class ElasticNet:

@@ -128,7 +128,7 @@ def _enet_cv_scan_sharded(Xtr, ytr, Xva, yva, lambda1s, lambda2,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes), P(axes), P(axes), P(axes), P(), P()),
-                     out_specs=(P(None, axes),) * 3, check_vma=False)(
+                     out_specs=(P(None, axes),) * 5, check_vma=False)(
                          Xtr, ytr, Xva, yva, lambda1s, lambda2)
 
 
@@ -164,7 +164,8 @@ def _enet_cv_scan(Xtr, ytr, Xva, yva, lambda1s, lambda2,
                                              config)
                 resid = Xv1 @ pt.beta - yv1
                 return carry2, (jnp.mean(resid * resid)[None],
-                                pt.n_kept[None], pt.evals[None])
+                                pt.n_kept[None], pt.evals[None],
+                                pt.cg_steps[None], pt.stop[None])
 
             _, out = jax.lax.scan(lam_body1, api.cold_carry(Xf, yf), lambda1s)
             return None, out                           # each (L, 1)
@@ -178,17 +179,18 @@ def _enet_cv_scan(Xtr, ytr, Xva, yva, lambda1s, lambda2,
             carry2, pts = jax.vmap(one)(Xt, yt, carry)
             resid = jnp.einsum("kif,kf->ki", Xv, pts.beta) - yv
             mse = jnp.mean(resid * resid, axis=1)      # (c,)
-            return carry2, (mse, pts.n_kept, pts.evals)
+            return carry2, (mse, pts.n_kept, pts.evals, pts.cg_steps,
+                            pts.stop)
 
         _, out = jax.lax.scan(lam_body, init, lambda1s)
         return None, out                               # each (L, c)
 
-    _, (mse, n_kept, evals) = jax.lax.scan(chunk_body, None, chunked)
+    _, out = jax.lax.scan(chunk_body, None, chunked)
 
     def reorder(a):                                    # (g, L, c) -> (L, k)
         return jnp.moveaxis(a, 0, 1).reshape(a.shape[1], k)
 
-    return reorder(mse), reorder(n_kept), reorder(evals)
+    return tuple(reorder(a) for a in out)
 
 
 class CVResult(NamedTuple):
@@ -202,6 +204,8 @@ class CVResult(NamedTuple):
     intercept: jax.Array
     n_kept: jax.Array       # (L, k) screened problem sizes
     evals: jax.Array        # (L, k) SVEN solves per (lambda, fold)
+    cg_steps: jax.Array     # (L, k) CG iterations per (lambda, fold)
+    stop: jax.Array         # (L, k) root-find stop codes (api.STOP_*)
 
 
 def cross_validate(X, y, *, k: int = 5, lambda1s=None, n_lambdas: int = 40,
@@ -268,12 +272,11 @@ def cross_validate(X, y, *, k: int = 5, lambda1s=None, n_lambdas: int = 40,
     Xtr, ytr, Xva, yva = cv_folds(Xs, ys, k)
     if mesh is not None:
         Xtr, ytr, Xva, yva = _place_folds(mesh, Xtr, ytr, Xva, yva)
-        mse, n_kept, evals = _enet_cv_scan_sharded(Xtr, ytr, Xva, yva,
-                                                   lambda1s, lam2, config,
-                                                   chunk_local, mesh)
+        mse, n_kept, evals, cg_steps, stop = _enet_cv_scan_sharded(
+            Xtr, ytr, Xva, yva, lambda1s, lam2, config, chunk_local, mesh)
     else:
-        mse, n_kept, evals = _enet_cv_scan(Xtr, ytr, Xva, yva, lambda1s,
-                                           lam2, config, fold_chunk)
+        mse, n_kept, evals, cg_steps, stop = _enet_cv_scan(
+            Xtr, ytr, Xva, yva, lambda1s, lam2, config, fold_chunk)
     mean_mse = jnp.mean(mse, axis=1)
     i_min = int(jnp.argmin(mean_mse))
     lambda_min = float(lambda1s[i_min])
@@ -283,7 +286,8 @@ def cross_validate(X, y, *, k: int = 5, lambda1s=None, n_lambdas: int = 40,
     beta, intercept = api.unscale_coef(pt.beta, scaler)
     return CVResult(lambda1s=lambda1s, lambda2=float(lambda2), mse_path=mse,
                     mean_mse=mean_mse, lambda_min=lambda_min, index_min=i_min,
-                    beta=beta, intercept=intercept, n_kept=n_kept, evals=evals)
+                    beta=beta, intercept=intercept, n_kept=n_kept, evals=evals,
+                    cg_steps=cg_steps, stop=stop)
 
 
 def cross_validate_reference(X, y, *, k: int = 5, lambda1s=None,

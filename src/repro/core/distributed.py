@@ -371,11 +371,11 @@ def _sven_sharded_primal(mesh: Mesh, X, y, t, C, warm_w, config):
                                   cg_iters=config.cg_iters, w0=w0)
         alpha = C_op * jnp.maximum(1.0 - yhat * matvec(res.w), 0.0)
         beta = red.recover_beta(alpha, t_op)
-        return beta, alpha, res.w, res.iters, res.grad_norm
+        return beta, alpha, res.w, res.iters, res.grad_norm, res.cg_steps
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes, None), P(axes), P(), P(), P()),
-                     out_specs=(P(), P(), P(), P(), P()), check_vma=False)(
+                     out_specs=(P(),) * 6, check_vma=False)(
                          X, y, jnp.asarray(t, dtype), jnp.asarray(C, dtype),
                          warm_w)
 
@@ -409,6 +409,7 @@ def _sven_sharded_dual_jit(stats, K, X, y, t, lambda2, warm_alpha, *,
               else solve_dual_fista)
     res = solver(lambda v: K @ v, 2 * p, C, dtype=dtype, tol=config.tol,
                  alpha0=warm_alpha)
+    cg_steps = res.cg_steps
     if kernel_K and config.precision != "f32":
         # iterative refinement, sharded flavor (DESIGN.md §10.3): re-solve
         # matrix-free at full precision from the low-precision alpha. All
@@ -416,13 +417,15 @@ def _sven_sharded_dual_jit(stats, K, X, y, t, lambda2, warm_alpha, *,
         # the same one-psum-per-product collectives as the stats path.
         res = solver(red.SvenOperator(X=X, y=y, t=t).kernel_matvec, 2 * p, C,
                      dtype=dtype, tol=config.tol, alpha0=res.alpha)
+        cg_steps = cg_steps + res.cg_steps
     beta = red.recover_beta(res.alpha, t)
     # w = Zhat @ alpha on the row-sharded X: global ops, the partitioner
     # keeps the row dimension sharded and gathers the (n,) result.
     w = red.SvenOperator(X=X, y=y, t=t).zhat_matvec(res.alpha)
     kkt = en.kkt_violation(X, y, beta, lambda2)
     return SvenArrays(beta=beta, alpha=res.alpha, w=w[:n_orig],
-                      iters=res.iters, opt_residual=res.pg_norm, kkt=kkt)
+                      iters=res.iters, opt_residual=res.pg_norm, kkt=kkt,
+                      cg_steps=cg_steps)
 
 
 @partial(jax.jit, static_argnames=("mesh", "n_orig", "config"))
@@ -435,13 +438,13 @@ def _sven_sharded_primal_jit(X, y, t, lambda2, warm_w, *, mesh: Mesh,
     _bump_trace("sven_sharded")
     dtype = X.dtype
     C = red.svm_C(lambda2, floor=config.lambda2_floor).astype(dtype)
-    beta, alpha, w, iters, opt = _sven_sharded_primal(
+    beta, alpha, w, iters, opt, cg_steps = _sven_sharded_primal(
         mesh, X, y, t, C, warm_w, config)
     # KKT diagnostics on the (padded == original) problem; rows stay sharded
     # under the partitioner, one all-reduce for the X^T r contraction.
     kkt = en.kkt_violation(X, y, beta, lambda2)
     return SvenArrays(beta=beta, alpha=alpha, w=w[:n_orig], iters=iters,
-                      opt_residual=opt, kkt=kkt)
+                      opt_residual=opt, kkt=kkt, cg_steps=cg_steps)
 
 
 def sven_sharded(X: jax.Array, y: jax.Array, t, lambda2, config=None, *,

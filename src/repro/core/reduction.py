@@ -162,6 +162,7 @@ def gram_from_stats(G: jax.Array, u: jax.Array, s) -> jax.Array:
     return jnp.concatenate([top, bot], axis=0)
 
 
+@jax.named_scope("sven.gram")
 def gram_blocks(X: jax.Array, y: jax.Array, t: float) -> jax.Array:
     """Assemble K = Zhat^T Zhat (2p x 2p) from p x p blocks.
 
@@ -172,6 +173,7 @@ def gram_blocks(X: jax.Array, y: jax.Array, t: float) -> jax.Array:
     return gram_from_stats(X.T @ X, (X.T @ y) / t, (y @ y) / (t * t))
 
 
+@jax.named_scope("sven.gram")
 def gram_reference(X: jax.Array, y: jax.Array, t: float) -> jax.Array:
     """Paper-faithful K: materialize Zhat then Zhat^T Zhat."""
     Xhat, yhat = build_svm_dataset(X, y, t)

@@ -85,6 +85,7 @@ class SvenArrays(NamedTuple):
     iters: jax.Array
     opt_residual: jax.Array
     kkt: jax.Array
+    cg_steps: jax.Array       # CG iterations over all Newton steps (0: FISTA)
 
 
 class SvenSolution(NamedTuple):
@@ -249,7 +250,8 @@ def _sven_core(
             beta = beta * keepf
         return SvenArrays(beta=beta, alpha=alpha, w=res.w, iters=res.iters,
                           opt_residual=res.grad_norm,
-                          kkt=en.kkt_violation(X_full, y, beta, lambda2))
+                          kkt=en.kkt_violation(X_full, y, beta, lambda2),
+                          cg_steps=res.cg_steps)
 
     # --- dual ---
     m = 2 * p
@@ -275,6 +277,7 @@ def _sven_core(
 
     solver = solve_dual_newton if config.solver == "newton" else solve_dual_fista
     res = solver(kernel_matvec, m, C, dtype=dtype, tol=config.tol, alpha0=warm_alpha)
+    cg_steps = res.cg_steps
     if refine:
         # one step of iterative refinement (DESIGN.md §10.3): the bf16/tf32
         # kernel bought the O(np^2) Gram pass cheap; re-solving MATRIX-FREE
@@ -284,6 +287,7 @@ def _sven_core(
         # steps — restoring <= 1e-10 parity with the full-precision solve.
         res = solver(op.kernel_matvec, m, C, dtype=dtype, tol=config.tol,
                      alpha0=res.alpha)
+        cg_steps = cg_steps + res.cg_steps
     beta = red.recover_beta(res.alpha, t)
     if keepf is not None:
         beta = beta * keepf
@@ -292,7 +296,8 @@ def _sven_core(
     w = op.zhat_matvec(res.alpha)
     return SvenArrays(beta=beta, alpha=res.alpha, w=w, iters=res.iters,
                       opt_residual=res.pg_norm,
-                      kkt=en.kkt_violation(X_full, y, beta, lambda2))
+                      kkt=en.kkt_violation(X_full, y, beta, lambda2),
+                      cg_steps=cg_steps)
 
 
 @partial(jax.jit, static_argnames=("config",))

@@ -91,4 +91,5 @@ def solve_dual_fista(
     hyper = make_hyper(C, tol, dtype)
     st = machine.run(hyper, alpha0)
     return DualResult(alpha=st.x, iters=st.iters, pg_norm=st.residual,
-                      objective=_dual_obj(kernel_matvec, st.x, hyper.C))
+                      objective=_dual_obj(kernel_matvec, st.x, hyper.C),
+                      cg_steps=jnp.zeros((), jnp.int32))   # no CG in FISTA

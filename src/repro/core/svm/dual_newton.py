@@ -18,7 +18,8 @@ product. All compute is matmul/matvec-shaped for MXU/BLAS execution.
 
 Expressed as a `SolverState` init/step/run machine (state.py, DESIGN.md §6)
 with traced (C, tol) so one trace serves scan-compiled paths and vmapped
-problem batches.
+problem batches. The machine's `aux` is the int32 count of masked-CG
+iterations spent so far (`DualResult.cg_steps`).
 """
 from __future__ import annotations
 
@@ -36,13 +37,16 @@ class DualResult(NamedTuple):
     iters: jax.Array
     pg_norm: jax.Array      # projected-gradient sup-norm
     objective: jax.Array
+    cg_steps: jax.Array     # CG iterations over the Newton steps (0: FISTA)
 
 
-def _masked_cg(matvec: Callable, b: jax.Array, mask: jax.Array, maxiter: int, tol) -> jax.Array:
-    """CG restricted to coordinates where mask==1 (others pinned to 0)."""
+def _masked_cg(matvec: Callable, b: jax.Array, mask: jax.Array, maxiter: int, tol):
+    """CG restricted to coordinates where mask==1 (others pinned to 0).
+    Returns (x, iterations run)."""
 
     def mv(v):
-        return mask * matvec(mask * v)
+        with jax.named_scope("sven.hess_mv"):
+            return mask * matvec(mask * v)
 
     b = mask * b
 
@@ -62,8 +66,10 @@ def _masked_cg(matvec: Callable, b: jax.Array, mask: jax.Array, maxiter: int, to
         return (rs > tol * tol) & (it < maxiter)
 
     x0 = jnp.zeros_like(b)
-    x, *_ = jax.lax.while_loop(cond, body, (x0, b, b, b @ b, jnp.zeros((), jnp.int32)))
-    return x
+    with jax.named_scope("sven.cg"):
+        x, _, _, _, it = jax.lax.while_loop(
+            cond, body, (x0, b, b, b @ b, jnp.zeros((), jnp.int32)))
+    return x, it
 
 
 def _dual_obj(kernel_matvec, alpha, C):
@@ -89,8 +95,9 @@ def dual_newton_machine(
     def init(hyper: Hyper, x0: jax.Array | None = None) -> SolverState:
         del hyper
         a0 = jnp.zeros((m,), dtype) if x0 is None else x0.astype(dtype)
-        return initial_state(a0)
+        return initial_state(a0, aux=jnp.zeros((), jnp.int32))
 
+    @jax.named_scope("sven.newton")
     def step(state: SolverState, hyper: Hyper) -> SolverState:
         alpha, C = state.x, hyper.C
         g = grad_fn(alpha, C)
@@ -99,7 +106,7 @@ def dual_newton_machine(
         def hess_mv(v):
             return two * kernel_matvec(v) + v / C
 
-        d = _masked_cg(hess_mv, g, free, cg_iters, hyper.tol * 1e-2)
+        d, cg_it = _masked_cg(hess_mv, g, free, cg_iters, hyper.tol * 1e-2)
 
         f0 = _dual_obj(kernel_matvec, alpha, C)
 
@@ -123,7 +130,8 @@ def dual_newton_machine(
         g_new = grad_fn(alpha_new, C)
         pg = jnp.max(jnp.abs(jnp.where(alpha_new > 0, g_new, jnp.minimum(g_new, 0.0))))
         # ~(> tol): NaN residual is terminal (diverged), not "keep iterating"
-        return SolverState(x=alpha_new, aux=state.aux, iters=state.iters + 1,
+        return SolverState(x=alpha_new, aux=state.aux + cg_it,
+                           iters=state.iters + 1,
                            residual=pg, converged=~(pg > hyper.tol))
 
     def run(hyper: Hyper, x0: jax.Array | None = None) -> SolverState:
@@ -149,4 +157,5 @@ def solve_dual_newton(
     hyper = make_hyper(C, tol, dtype)
     st = machine.run(hyper, alpha0)
     return DualResult(alpha=st.x, iters=st.iters, pg_norm=st.residual,
-                      objective=_dual_obj(kernel_matvec, st.x, hyper.C))
+                      objective=_dual_obj(kernel_matvec, st.x, hyper.C),
+                      cg_steps=st.aux)

@@ -13,6 +13,10 @@ fixed SV set f is quadratic, so the method takes full steps near the
 solution and terminates in a handful of iterations — all heavy work is
 BLAS-3-shaped, which is the property the paper's GPU claim rests on.
 
+The machine's `aux` is the int32 count of CG iterations spent so far
+(`PrimalResult.cg_steps`): `_cg` returns the iteration count its loop
+already carries, and each Newton step adds it.
+
 The solver is a `SolverState` init/step/run machine (state.py, DESIGN.md
 §6): hyperparameters (C, tol) are traced scalars, the carry is fixed-shape,
 and everything is jax.lax control flow — so one trace serves a whole
@@ -36,14 +40,17 @@ class PrimalResult(NamedTuple):
     iters: jax.Array
     grad_norm: jax.Array
     objective: jax.Array
+    cg_steps: jax.Array     # CG iterations summed over the Newton steps
 
 
-def _cg(matvec: Callable, b: jax.Array, maxiter: int, tol) -> jax.Array:
-    """Plain CG on SPD `matvec`; fixed-shape while_loop, early exit on tol."""
+def _cg(matvec: Callable, b: jax.Array, maxiter: int, tol):
+    """Plain CG on SPD `matvec`; fixed-shape while_loop, early exit on tol.
+    Returns (x, iterations run)."""
 
     def body(state):
         x, r, pvec, rs, it = state
-        Ap = matvec(pvec)
+        with jax.named_scope("sven.hess_mv"):
+            Ap = matvec(pvec)
         denom = pvec @ Ap
         alpha = rs / jnp.where(denom > 0, denom, 1.0)
         x = x + alpha * pvec
@@ -59,8 +66,9 @@ def _cg(matvec: Callable, b: jax.Array, maxiter: int, tol) -> jax.Array:
 
     x0 = jnp.zeros_like(b)
     state = (x0, b, b, b @ b, jnp.zeros((), jnp.int32))
-    x, *_ = jax.lax.while_loop(cond, body, state)
-    return x
+    with jax.named_scope("sven.cg"):
+        x, _, _, _, it = jax.lax.while_loop(cond, body, state)
+    return x, it
 
 
 def _primal_obj(matvec: Callable, yhat: jax.Array, w: jax.Array, C) -> jax.Array:
@@ -90,8 +98,9 @@ def primal_newton_machine(
     def init(hyper: Hyper, x0: jax.Array | None = None) -> SolverState:
         del hyper
         w0 = jnp.zeros((d,), dtype) if x0 is None else x0.astype(dtype)
-        return initial_state(w0)
+        return initial_state(w0, aux=jnp.zeros((), jnp.int32))
 
+    @jax.named_scope("sven.newton")
     def step(state: SolverState, hyper: Hyper) -> SolverState:
         w, C = state.x, hyper.C
         o = matvec(w)
@@ -105,7 +114,7 @@ def primal_newton_machine(
             def hess_mv(v):
                 return hess_matvec(v, act, C)
 
-        dstep = _cg(hess_mv, grad, cg_iters, hyper.tol * 1e-2)
+        dstep, cg_it = _cg(hess_mv, grad, cg_iters, hyper.tol * 1e-2)
 
         # Backtracking (Armijo) line search on f along -dstep, LINEARIZED:
         # matvec is linear, so Xhat (w - s d) = o - s (Xhat d) — one extra
@@ -142,7 +151,8 @@ def primal_newton_machine(
         gnorm = jnp.max(jnp.abs(grad))
         # ~(> tol) rather than (<= tol): a NaN residual counts as terminal,
         # so a diverged solve exits instead of spinning to max_iters.
-        return SolverState(x=w - s * dstep, aux=state.aux, iters=state.iters + 1,
+        return SolverState(x=w - s * dstep, aux=state.aux + cg_it,
+                           iters=state.iters + 1,
                            residual=gnorm, converged=~(gnorm > hyper.tol))
 
     def run(hyper: Hyper, x0: jax.Array | None = None) -> SolverState:
@@ -172,4 +182,5 @@ def solve_primal_newton(
     hyper = make_hyper(C, tol, dtype)
     st = machine.run(hyper, w0)
     return PrimalResult(w=st.x, iters=st.iters, grad_norm=st.residual,
-                        objective=_primal_obj(matvec, yhat, st.x, hyper.C))
+                        objective=_primal_obj(matvec, yhat, st.x, hyper.C),
+                        cg_steps=st.aux)

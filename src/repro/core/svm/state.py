@@ -13,7 +13,8 @@ with one common carry:
     SolverState(x, aux, iters, residual, converged)
 
 `x` is the solver's iterate (primal w or dual alpha), `aux` holds any
-solver-private fixed-shape extras (FISTA momentum), `residual` is the
+solver-private fixed-shape extras (FISTA momentum; the Newton solvers'
+running count of CG iterations), `residual` is the
 solver's own optimality measure and `converged` its tolerance flag. Because
 the carry is a fixed-shape pytree and the hyperparameters (`Hyper.C`,
 `Hyper.tol`) enter as *traced scalars* — never Python floats baked into the
@@ -41,7 +42,7 @@ class SolverState(NamedTuple):
     """Common fixed-shape carry shared by all SVM solver machines."""
 
     x: jax.Array          # iterate: primal w (n,) or dual alpha (2p,)
-    aux: Any              # solver-private extras (fixed-shape pytree, often ())
+    aux: Any              # solver-private extras (fixed-shape pytree)
     iters: jax.Array      # int32 outer-iteration count
     residual: jax.Array   # solver's optimality measure (sup-norm)
     converged: jax.Array  # bool: residual <= tol reached

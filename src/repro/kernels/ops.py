@@ -125,6 +125,7 @@ def _gram_tiles(backend: str, n: int, p: int, bm, bn, bk,
 
 # -- shifted Gram -----------------------------------------------------------
 
+@jax.named_scope("sven.gram")
 def shifted_gram(
     X: jax.Array,
     y: jax.Array,

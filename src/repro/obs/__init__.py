@@ -11,7 +11,9 @@ Three pillars, all host-side and hot-path safe:
              coordinator aggregates over (`MetricsRegistry`);
     solve    per-solve records (iterations, KKT, keep-fraction, route,
              modeled-vs-actual seconds) feeding the cost-model residual
-             report that validates `core.routing` (`SolveLog`).
+             report that validates `core.routing` (`SolveLog`), and one
+             record per `enet_path` call (evaluations, CG steps, root-find
+             stop causes) in the process-wide `default_solve_log()`.
 
 Plus `events` (bounded ring of structured JSONL events — host death,
 requeue, deadline_exceeded, cache corruption, speculation hit/miss) and
@@ -30,7 +32,8 @@ from repro.obs import clock
 from repro.obs.events import EventLog, default_events, dump_on_exit, emit
 from repro.obs.metrics import (Counter, ExponentialHistogram, Gauge,
                                Histogram, MetricsRegistry, default_registry)
-from repro.obs.solve import SolveLog, SolveRecord
+from repro.obs.solve import (PathRecord, SolveLog, SolveRecord,
+                             default_solve_log)
 from repro.obs.trace import (Tracer, disable_tracing, enable_tracing,
                              get_tracer)
 
@@ -40,7 +43,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "ExponentialHistogram",
     "MetricsRegistry", "default_registry",
     "EventLog", "default_events", "emit", "dump_on_exit",
-    "SolveLog", "SolveRecord",
+    "SolveLog", "SolveRecord", "PathRecord", "default_solve_log",
 ]
 
 if os.environ.get("REPRO_TRACE", "") not in ("", "0"):

@@ -15,14 +15,30 @@ route path, the distribution of log10(actual/modeled). A drifting residual
 is the signal to re-run `core.routing.calibrate(force=True)` — this is the
 data needed to validate and later recalibrate the router, closing the PR 6
 loop.
+
+Every `core.api.enet_path` call also appends one `PathRecord` to the
+process-wide `default_solve_log()`: its per-point root-find evaluations,
+inner solver iterations, CG steps and root-find stop causes, as the device
+arrays the call returned. Recording never syncs; the arrays come to the
+host only when the log is read. This is where an operator finds
+uncertified points: `SolveLog.summary()` counts the points by stop
+cause, and any under "bracket" or "max_evals" ended with |nu - lambda1|
+above the root-find's tolerance (`core.api.STOP_*`); `path_records()`
+says which call and which point.
 """
 from __future__ import annotations
 
 import collections
 import math
-from typing import List, NamedTuple
+from typing import Any, List, NamedTuple
 
-__all__ = ["SolveRecord", "SolveLog"]
+import numpy as np
+
+__all__ = ["SolveRecord", "PathRecord", "SolveLog", "STOP_CAUSES",
+           "default_solve_log"]
+
+#: names of the root-find stop codes (`EnetPoint.stop`), by code
+STOP_CAUSES = ("no_root", "root", "bracket", "max_evals")
 
 
 class SolveRecord(NamedTuple):
@@ -42,14 +58,28 @@ class SolveRecord(NamedTuple):
     keep_fraction: float    # nonzero share of the solution (screening keep)
 
 
+class PathRecord(NamedTuple):
+    """One `enet_path` call: (L,) per-point counters, device or host arrays."""
+
+    evals: Any        # root-find evaluations (SVEN solves)
+    sven_iters: Any   # inner solver iterations
+    cg_steps: Any     # CG iterations
+    stop: Any         # root-find stop code, indexes STOP_CAUSES
+
+    def host(self) -> "PathRecord":
+        """The same record with every field as a NumPy array (syncs)."""
+        return PathRecord(*(np.asarray(f) for f in self))
+
+
 class SolveLog:
-    """Bounded log of `SolveRecord`s with a cost-model residual report."""
+    """Bounded log of `SolveRecord`s (served batches) and `PathRecord`s
+    (path calls), with a cost-model residual report and a path summary."""
 
     def __init__(self, *, capacity: int = 4096) -> None:
         self._records: collections.deque = collections.deque(maxlen=capacity)
         self.recorded = 0
 
-    def add(self, record: SolveRecord) -> None:
+    def add(self, record) -> None:
         self._records.append(record)
         self.recorded += 1
 
@@ -57,7 +87,23 @@ class SolveLog:
         return len(self._records)
 
     def records(self) -> List[SolveRecord]:
-        return list(self._records)
+        return [r for r in self._records if isinstance(r, SolveRecord)]
+
+    def path_records(self) -> List[PathRecord]:
+        """The retained `PathRecord`s, oldest first, on the host."""
+        return [r.host() for r in self._records if isinstance(r, PathRecord)]
+
+    def summary(self) -> dict:
+        """Points of the retained path calls by stop cause, and CG steps
+        per point."""
+        recs = self.path_records()
+        stop = np.concatenate([r.stop for r in recs]) if recs else np.zeros(0)
+        cg = np.concatenate([r.cg_steps for r in recs]) if recs else np.zeros(0)
+        return {"paths": len(recs), "points": int(stop.size),
+                "by_stop": {name: int(np.sum(stop == code))
+                            for code, name in enumerate(STOP_CAUSES)},
+                "cg_steps_per_point": (float(np.mean(cg)) if cg.size
+                                       else None)}
 
     def residual_report(self) -> dict:
         """Modeled-vs-actual summary per route path.
@@ -71,7 +117,8 @@ class SolveLog:
         """
         by_path: dict = {}
         unmodeled = 0
-        for r in self._records:
+        records = self.records()
+        for r in records:
             if r.modeled_s <= 0.0 or r.actual_s <= 0.0:
                 unmodeled += 1
                 continue
@@ -89,9 +136,18 @@ class SolveLog:
                 "log10_ratio_p50": ratios[n // 2],
                 "log10_ratio_max_abs": max(abs(ratios[0]), abs(ratios[-1])),
             }
-        return {"n_records": len(self._records), "n_unmodeled": unmodeled,
+        return {"n_records": len(records), "n_unmodeled": unmodeled,
                 "by_path": paths}
 
     def clear(self) -> None:
         self._records.clear()
         self.recorded = 0
+
+
+_DEFAULT = SolveLog()
+
+
+def default_solve_log() -> SolveLog:
+    """Process-wide log that `core.api.enet_path` appends a `PathRecord`
+    to per call — per-scheduler batch records live on their own log."""
+    return _DEFAULT

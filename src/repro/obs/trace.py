@@ -3,8 +3,9 @@
 
 A `Tracer` records nested spans on the host's monotonic ns clock into a
 bounded deque — no device syncs, no allocation beyond one tuple per span,
-and a disabled tracer costs one attribute check per span site, so the
-instrumentation can stay in the serving hot path permanently (the
+and a disabled tracer costs one attribute check and one profiler
+annotation per span site, so the instrumentation can stay in the serving
+and solver hot paths permanently (the
 telemetry-overhead gate in ``benchmarks/bench_obs.py`` holds enabled
 tracing to <= 1.10x disabled p99).
 
@@ -20,11 +21,18 @@ Span taxonomy (DESIGN.md §12.1 — the names CI schema-checks for):
     route          router decision instant (path + full price table)
     trace:<entry>  solver (re)trace instant — nonzero steady-state count
                    is the regression the zero-retrace CI gate catches
+    enet_path.prepare   standardize, grid and config of one `enet_path`
+                        (`enet`, `enet_batch`) call
+    enet_path.dispatch  the jitted solve's dispatch
+    enet_path.unscale   coefficients back to the original scale
 
 Export is Chrome-trace JSON (``chrome://tracing`` / Perfetto: "X" complete
-events, µs timestamps). With ``annotate=True`` each span also enters a
-`jax.profiler.TraceAnnotation`, so when a jax profile is being captured the
-host spans line up with device timelines in the same Perfetto view.
+events, µs timestamps). Every span, recorded or not, also enters a
+`jax.profiler.TraceAnnotation`: while a jax profile is being taken (by an
+operator, or by the benchmark's traced run) the spans appear on the
+profile's own clock beside the device's operations, and while none is, the
+annotation records nothing. A disabled tracer hands out that annotation
+alone, one object per span.
 """
 from __future__ import annotations
 
@@ -34,17 +42,20 @@ import os
 import threading
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs import clock as _clock
 
 __all__ = ["Tracer", "get_tracer", "enable_tracing", "disable_tracing"]
 
 
-def _jax_annotation(name: str):
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — profiler API absent: spans still work
-        return None
+class _ProfilerSpan(TraceAnnotation):
+    """The span a disabled tracer hands out: the profiler annotation alone
+    (records nothing unless a profile is being taken), with the `args`
+    attribute call sites may test."""
+
+    __slots__ = ()
+    args = None
 
 
 class _Span:
@@ -66,44 +77,24 @@ class _Span:
         stack = tr._stack()
         self.parent = stack[-1] if stack else None
         stack.append(self.name)
-        self.annot = None
-        if tr.annotate:
-            annot = _jax_annotation(self.name)
-            if annot is not None:
-                annot.__enter__()
-                self.annot = annot
+        self.annot = TraceAnnotation(self.name)
+        self.annot.__enter__()
         self.t0 = _clock.monotonic_ns()
         return self
 
     def __exit__(self, *exc):
         tr = self.tracer
-        if not tr.enabled or self.t0 is None:
-            return False   # disabled, or toggled mid-span: record nothing
+        if self.t0 is None:
+            return False   # disabled at entry: nothing was opened
         dur = _clock.monotonic_ns() - self.t0
-        if self.annot is not None:
-            self.annot.__exit__(*exc)
+        self.annot.__exit__(*exc)
         stack = tr._stack()
         if stack and stack[-1] == self.name:
             stack.pop()
+        if not tr.enabled:
+            return False   # toggled off mid-span: record nothing
         tr._record("X", self.name, self.parent, self.t0, dur, self.args)
         return False
-
-
-class _NoopSpan:
-    """Shared do-nothing span handed out while tracing is disabled — keeps
-    the disabled hot path allocation-free (no `_Span` per call site)."""
-
-    __slots__ = ()
-    args = None
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_SPAN = _NoopSpan()
 
 
 class Tracer:
@@ -111,7 +102,6 @@ class Tracer:
 
     def __init__(self, *, capacity: int = 200_000) -> None:
         self.enabled = False
-        self.annotate = False
         self._spans: collections.deque = collections.deque(maxlen=capacity)
         self._counts: collections.Counter = collections.Counter()
         self._tls = threading.local()
@@ -129,14 +119,12 @@ class Tracer:
 
     # -- control -----------------------------------------------------------
 
-    def enable(self, *, annotate: bool = False) -> "Tracer":
+    def enable(self) -> "Tracer":
         self.enabled = True
-        self.annotate = annotate
         return self
 
     def disable(self) -> None:
         self.enabled = False
-        self.annotate = False
 
     def reset(self) -> None:
         self._spans.clear()
@@ -146,7 +134,7 @@ class Tracer:
 
     def span(self, name: str, **args):
         if not self.enabled:
-            return _NOOP_SPAN
+            return _ProfilerSpan(name)
         return _Span(self, name, args or None)
 
     def traced(self, name: Optional[str] = None):
@@ -215,8 +203,8 @@ def get_tracer() -> Tracer:
     return _TRACER
 
 
-def enable_tracing(*, annotate: bool = False) -> Tracer:
-    return _TRACER.enable(annotate=annotate)
+def enable_tracing() -> Tracer:
+    return _TRACER.enable()
 
 
 def disable_tracing() -> None:
