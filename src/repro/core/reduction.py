@@ -135,6 +135,24 @@ class SvenOperator:
         """K v with K = Zhat^T Zhat (2p x 2p), in O(np)."""
         return self.zhat_rmatvec(self.zhat_matvec(v))
 
+    def xhat_weighted_gram(self, c: jax.Array) -> jax.Array:
+        """Xhat^T diag(c) Xhat (n x n) for sample weights c = [c_t ; c_b]:
+
+            X diag(c_t + c_b) X^T - (u y^T + y u^T)/t + (sum(c)/t^2) y y^T,
+            u = X (c_t - c_b)
+
+        One n x p x n GEMM plus rank-2 terms; the (2p, n) matrix never
+        exists. With c the hinge's active set this is the primal Newton
+        Hessian's data term, formed once per Newton step at small n.
+        """
+        p = self.p
+        ct, cb = c[:p], c[p:]
+        u = self.X @ (ct - cb)
+        yt = self.y / self.t
+        G = (self.X * (ct + cb)[None, :]) @ self.X.T
+        return (G - (u[:, None] * yt[None, :] + yt[:, None] * u[None, :])
+                + jnp.sum(c) * (yt[:, None] * yt[None, :]))
+
     def margins(self, w: jax.Array) -> jax.Array:
         """yhat * (Xhat @ w) as used by the squared hinge."""
         p = self.p

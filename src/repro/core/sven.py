@@ -4,6 +4,10 @@ Dispatch (paper §3, "Implementation details"):
     2p > n  -> primal solver over w in R^n   (cost driven by n)
     else    -> dual solver over alpha in R^{2p}, kernel cached when it fits
 
+The primal solver's Newton Hessian is formed explicitly (n x n, once per
+Newton step) when n <= EXPLICIT_HESSIAN_MAX_N on the XLA backend, and
+applied matrix-free inside CG otherwise.
+
 `matrix_free=True` (default) uses the SvenOperator O(np) products and never
 materializes the (2p, n) constructed dataset — the TPU-native path.
 `matrix_free=False` is the paper-faithful baseline (explicit Xnew, as the
@@ -74,6 +78,27 @@ def trace_counts() -> dict:
 def reset_trace_counts() -> None:
     from repro.obs.metrics import default_registry
     default_registry().reset_instrument("solver_traces_total")
+
+
+#: Largest primal dimension d = n at which the XLA primal solve forms the
+#: Newton Hessian H = I + 2C Xhat^T diag(act) Xhat (n x n) once per Newton
+#: step and runs CG on H @ v, instead of two passes over X per CG step.
+#: Forming H costs one n x p x n GEMM; each CG step it saves costs two
+#: n x p GEMVs, which the TPU runs far below its GEMM rate (f64 emulation
+#: splits X anew on every CG step). On a TPU v5e at p = 22,283 in f64 one
+#: Newton step took 5.0x less time explicit at n = 85, 10.7x at 256, 10.5x
+#: at 512, 16.8x at 1024 and 14.3x at 2048 (PERF.md); 2048 is the largest n
+#: measured, so above it, where H's n^2 storage grows, CG stays matrix-free.
+EXPLICIT_HESSIAN_MAX_N = 2048
+
+
+def _bump_hessian_form(form: str) -> None:
+    """Count, at trace time, which Hessian form a primal solve took
+    (``sven_hessian_form_total{form="explicit"|"matrix_free"}``)."""
+    from repro.obs.metrics import default_registry
+    default_registry().counter(
+        "sven_hessian_form_total", "traced primal solves by Hessian form",
+        ("form",)).inc(form=form)
 
 
 class SvenArrays(NamedTuple):
@@ -239,10 +264,16 @@ def _sven_core(
                     precision=config.precision)
                 return hv.astype(dtype)
 
+        weighted_gram = None
+        if (config.matrix_free and hess_matvec is None
+                and n <= EXPLICIT_HESSIAN_MAX_N):
+            weighted_gram = op.xhat_weighted_gram
+        _bump_hessian_form("matrix_free" if weighted_gram is None
+                           else "explicit")
         res = solve_primal_newton(
             matvec, rmatvec, yhat, C, n,
             tol=config.tol, max_newton=config.max_newton, cg_iters=config.cg_iters,
-            w0=warm_w, hess_matvec=hess_matvec,
+            w0=warm_w, hess_matvec=hess_matvec, weighted_gram=weighted_gram,
         )
         alpha = C * jnp.maximum(1.0 - yhat * matvec(res.w), 0.0)  # Alg.1 line 7
         beta = red.recover_beta(alpha, t)

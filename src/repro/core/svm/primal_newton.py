@@ -7,11 +7,23 @@ Newton system at the current support-vector set SV = {i : margin_i < 1}:
     H = I + 2C Xhat_SV^T Xhat_SV
     H d = grad,   grad = w + 2C Xhat^T (act * (Xhat w - yhat))
 
-solved matrix-free with conjugate gradients (the H mat-vec is two Xhat
-products masked by `act`), followed by a backtracking line search. For a
-fixed SV set f is quadratic, so the method takes full steps near the
-solution and terminates in a handful of iterations — all heavy work is
-BLAS-3-shaped, which is the property the paper's GPU claim rests on.
+solved with conjugate gradients, followed by a backtracking line search.
+For a fixed SV set f is quadratic, so the method takes full steps near the
+solution and terminates in a handful of iterations.
+
+CG runs on H in one of two forms, chosen by the caller from the shape:
+
+- explicit (`weighted_gram` given): H (d x d) is formed once per Newton
+  step, under the scope `sven.hessian`, from one GEMM with X
+  (`SvenOperator.xhat_weighted_gram`), and each CG step is H @ v.
+  `core/sven.py` takes this form at small d, where the d x d matrix is
+  cheap and a pass over X per CG step is not.
+- matrix-free: each CG step applies H v = v + 2C Xhat^T (act * Xhat v),
+  two passes over X, or the caller's `hess_matvec` override (the fused
+  Pallas body, the feature-sharded mat-vec of `core/distributed.py`).
+
+The gradient, the line search and the stop test are matrix-free in both
+forms, so the fixed point does not depend on the form.
 
 The machine's `aux` is the int32 count of CG iterations spent so far
 (`PrimalResult.cg_steps`): `_cg` returns the iteration count its loop
@@ -88,8 +100,13 @@ def primal_newton_machine(
     max_newton: int = 50,
     cg_iters: int = 250,
     hess_matvec: Callable | None = None,          # (v, act, C) -> H v override (Pallas)
+    weighted_gram: Callable | None = None,        # c (m,) -> Xhat^T diag(c) Xhat (d, d)
 ) -> SolverMachine:
-    """Newton-CG as a SolverState machine; `hyper.C`/`hyper.tol` are traced."""
+    """Newton-CG as a SolverState machine; `hyper.C`/`hyper.tol` are traced.
+
+    With `weighted_gram`, CG runs on the explicit H formed once per Newton
+    step (and `hess_matvec` is not used); otherwise on `hess_matvec` or the
+    matrix-free product."""
     dtype = yhat.dtype
 
     def f_value(w, C):
@@ -107,7 +124,13 @@ def primal_newton_machine(
         act = ((yhat * o) < 1.0).astype(dtype)
         grad = w + 2.0 * C * rmatvec(act * (o - yhat))
 
-        if hess_matvec is None:
+        if weighted_gram is not None:
+            with jax.named_scope("sven.hessian"):
+                H = jnp.eye(d, dtype=dtype) + 2.0 * C * weighted_gram(act)
+
+            def hess_mv(v):
+                return H @ v
+        elif hess_matvec is None:
             def hess_mv(v):
                 return v + 2.0 * C * rmatvec(act * matvec(v))
         else:
@@ -173,12 +196,14 @@ def solve_primal_newton(
     cg_iters: int = 250,
     w0: jax.Array | None = None,
     hess_matvec: Callable | None = None,
+    weighted_gram: Callable | None = None,
 ) -> PrimalResult:
     """Classic-signature wrapper over the machine (C/tol may be traced)."""
     dtype = yhat.dtype
     machine = primal_newton_machine(matvec, rmatvec, yhat, d,
                                     max_newton=max_newton, cg_iters=cg_iters,
-                                    hess_matvec=hess_matvec)
+                                    hess_matvec=hess_matvec,
+                                    weighted_gram=weighted_gram)
     hyper = make_hyper(C, tol, dtype)
     st = machine.run(hyper, w0)
     return PrimalResult(w=st.x, iters=st.iters, grad_norm=st.residual,

@@ -1,6 +1,7 @@
 """The solver's own counters and stop causes (core/svm, core/sven.py,
 core/api.py), the path log they feed (obs/solve.py), the cold re-bracket of
 a collapsed root-find, and host spans in a jax profile (obs/trace.py)."""
+import importlib
 import inspect
 import sys
 from pathlib import Path
@@ -13,12 +14,15 @@ import pytest
 from repro.core import api, cross_validate, enet, enet_batch, enet_path
 from repro.core.reduction import SvenOperator, gram_blocks, svm_C
 from repro.core.sven import SvenConfig
-from repro.core.svm import solve_dual_newton, solve_primal_newton
+from repro.core.svm import primal_newton, solve_dual_newton, solve_primal_newton
 from repro.data.synthetic import make_regression
 from repro.obs import Tracer, default_solve_log, enable_tracing, get_tracer
+from repro.obs.metrics import default_registry
 from repro.obs.solve import STOP_CAUSES
 
 ROOT = Path(__file__).resolve().parents[1]
+# `repro.core.sven` the attribute is the function; the module holds the constant
+sven_mod = importlib.import_module("repro.core.sven")
 
 #: the loop bodies of `_cg` and `_masked_cg`
 _CG_BODIES = {"_cg.<locals>.body", "_masked_cg.<locals>.body"}
@@ -66,19 +70,33 @@ def test_cg_steps_equal_python_loop_count_in_the_solvers(mode):
     assert int(res.cg_steps) == count.n
 
 
-@pytest.mark.parametrize("mode", ["primal", "dual"])
+@pytest.mark.parametrize("mode", ["primal", "primal_matrix_free", "dual"])
 def test_path_cg_steps_equal_python_loop_count(mode, monkeypatch):
     """Through `_sven_core` and the Illinois loop: a path's cg_steps sum to
-    the CG iterations its solves ran."""
-    n, p = (20, 40) if mode == "primal" else (60, 8)
+    the CG iterations its solves ran. At n = 20 the primal solve runs CG on
+    the explicit Hessian, so the count is of the CG body's calls to the
+    operator `_cg` is handed (H @ v); `primal_matrix_free` lowers the
+    explicit form's limit below n and counts Xhat products as before."""
+    n, p = (60, 8) if mode == "dual" else (20, 40)
     X, y, _ = make_regression(n, p, k_true=4, seed=4)
     count = _CgCount()
-    name = "xhat_matvec" if mode == "primal" else "kernel_matvec"
-    monkeypatch.setattr(SvenOperator, name,
-                        count.wrap(getattr(SvenOperator, name)))
+    if mode == "primal":
+        cg = primal_newton._cg
+        monkeypatch.setattr(primal_newton, "_cg",
+                            lambda mv, *a: cg(count.wrap(mv), *a))
+    else:
+        if mode == "primal_matrix_free":
+            monkeypatch.setattr(sven_mod, "EXPLICIT_HESSIAN_MAX_N", n - 1)
+        name = "kernel_matvec" if mode == "dual" else "xhat_matvec"
+        monkeypatch.setattr(SvenOperator, name,
+                            count.wrap(getattr(SvenOperator, name)))
+    forms = default_registry().counter("sven_hessian_form_total",
+                                       labelnames=("form",))
+    explicit0 = forms.value(form="explicit")
     config = api.PathConfig(solver=SvenConfig(tol=1e-10, cache_kernel="never"))
     with jax.disable_jit():
         path = enet_path(X, y, n_lambdas=3, config=config)
+    assert (forms.value(form="explicit") > explicit0) == (mode == "primal")
     cg = np.asarray(path.cg_steps)
     assert cg[0] == 0 and np.all(cg[1:] > 0)
     assert int(cg.sum()) == count.n
