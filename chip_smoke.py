@@ -32,7 +32,12 @@ with the single-device answer in the same process: row-sharded `sven`
 (route="sharded") on the tall design, `sven_batch` of 4 one-off problems
 under `dist.mesh_context`, and `cross_validate` with k = 4 and an explicit
 mesh on a one-off design. Each must have produced its result on all 4
-devices.
+devices. Then `enet_path` over the tall design's 10-point glmnet grid under
+`dist.mesh_context`: its one-chip plan does not fit a chip, so it must take
+the row layout (`enet_path_layout_total`) and match coordinate descent at
+2 points; a profile of a second call counts the all-reduces each chip ran
+beside the path's root-find evaluations (reported, not judged). The CPU rehearsal reports no device memory, so it is handed a
+1 MiB chip for this check.
 
 Data comes from `data.synthetic.make_regression` with `--seed`. Results are
 checked against a plain reference: coordinate descent
@@ -360,10 +365,77 @@ class Smoke:
         devs["cv_mse"] = self.rel_dev(cv_mesh.mse_path, cv_single.mse_path)
         devs["cv_beta"] = self.rel_dev(cv_mesh.beta, cv_single.beta)
         out["cv_on_all"] = on_all(cv_mesh.mse_path)
+        out.update(self.rows_path(mesh, pc, devs))
         out.update(backend=backend, devs=devs, max_dev=max(devs.values()),
                    tol=TOL_F64, sharded_compile_s=c, sharded_run_s=r,
                    kkt=max(float(sharded.kkt), float(jnp.max(fan.kkt))))
         return out
+
+    def rows_path(self, mesh, pc, devs: dict) -> dict:
+        """`enet_path` on the tall design under the mesh: the row layout,
+        its answers at two grid points, and its all-reduces."""
+        from repro import dist
+        from repro.core import api, routing
+        from repro.obs.metrics import default_registry
+        np = self.np
+        if self.args.rehearse:
+            routing.chip_memory_bytes = lambda device: 1 << 20
+        X, y = self.data("msd")
+        grid = api.lambda_grid(X, y, n_lambdas=N_LAMBDAS)
+        layouts = default_registry().counter(
+            "enet_path_layout_total", "enet_path calls by row layout",
+            ("layout",))
+        before = layouts.series().get(("rows",), 0)
+
+        def call():
+            with dist.mesh_context(mesh):
+                return api.enet_path(X, y, lambda1s=grid, lambda2=LAMBDA2,
+                                     config=pc)
+
+        path, c, r = self.timed("mesh/rows", call)
+        idx = (1, 3)
+        refs = self.cd_reference("msd", [float(grid[i]) for i in idx])
+        devs["rows"] = max(self.rel_dev(path.betas[i], ref)
+                           for i, ref in zip(idx, refs))
+        reduces = self.all_reduces(call)
+        return dict(rows_layout=layouts.series().get(("rows",), 0)
+                    == before + 2,
+                    rows_on_all=len(path.betas.sharding.device_set)
+                    == mesh.size,
+                    rows_compile_s=c, rows_run_s=r,
+                    rows_all_reduces_per_chip=reduces,
+                    rows_evals=int(np.sum(np.asarray(path.evals))))
+
+    def all_reduces(self, fn):
+        """All-reduce operations each device ran in a call of fn(), from a
+        profile of it (the "XLA Ops" line of each device plane)."""
+        import glob
+        import shutil
+        import tempfile
+
+        from jax.profiler import ProfileData
+        jax = self.jax
+        d = tempfile.mkdtemp(prefix="chip-smoke-trace-")
+        try:
+            jax.profiler.start_trace(d)
+            try:
+                jax.block_until_ready(fn())
+            finally:
+                jax.profiler.stop_trace()
+            path = max(glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                                 recursive=True), key=os.path.getmtime)
+            counts = []
+            for plane in ProfileData.from_file(path).planes:
+                if (plane.name.startswith("/device:")
+                        and "CPU" not in plane.name):
+                    counts.append(sum(
+                        1 for line in plane.lines if line.name == "XLA Ops"
+                        for e in line.events
+                        if "all-reduce" in e.name.partition(" = ")[0]
+                        and "done" not in e.name.partition(" = ")[0]))
+            return counts
+        finally:
+            shutil.rmtree(d, ignore_errors=True)
 
 
 def _passed(phase: str, backend: str, rec: dict) -> bool:
@@ -376,7 +448,8 @@ def _passed(phase: str, backend: str, rec: dict) -> bool:
                 and rec["max_dev_over_tol"] <= 1.0)
     if phase == "mesh":
         return (rec["sharded_on_all"] and rec["batch_on_all"]
-                and rec["cv_on_all"] and rec["max_dev"] <= rec["tol"])
+                and rec["cv_on_all"] and rec["rows_layout"]
+                and rec["rows_on_all"] and rec["max_dev"] <= rec["tol"])
     return rec["max_dev"] <= rec["tol"]
 
 

@@ -29,7 +29,6 @@ from repro.core.reduction import (
 from repro.core import elastic_net
 from repro.core.distributed import (
     sharded_gram_stats,
-    sharded_hinge_stats,
     sven_sharded,
 )
 from repro.core.routing import (
@@ -88,7 +87,6 @@ __all__ = [
     # data-parallel sharded solve path (core/distributed.py, DESIGN.md §9)
     "sven_sharded",
     "sharded_gram_stats",
-    "sharded_hinge_stats",
     # adaptive layout routing (core/routing.py, DESIGN.md §9.5)
     "sven_routed",
     "route_solve",

@@ -595,11 +595,26 @@ def enet_path(X, y, *, lambda1s=None, n_lambdas: int = 40,
     re-solving with new data or a rescaled grid never retraces
     (`trace_counts()["enet_path_scan"]`).
 
+    Under `repro.dist.mesh_context(mesh)`, a design whose one-chip plan
+    does not fit a chip (`core.routing.route_path`) takes the row layout:
+    the standardized rows are zero-padded to a multiple of the mesh size
+    and placed over the mesh (`core.distributed.shard_rows`; arrays already
+    placed so stay where they are), and XLA's partitioner runs every pass
+    over X on each chip's rows, ending each reduction over rows in an
+    all-reduce. Zero rows change no answer, and the scaler reads the rows
+    as given, before padding. Arrays a caller placed with padding of its
+    own hold that padding as rows of the problem: pass them only with
+    `standardize=False, fit_intercept=False`.
+
     Besides the coefficients, every point reports its root-find
     evaluations, inner iterations, CG steps (0 with the FISTA solver, which
     runs no CG) and root-find stop code (`STOP_*`); each call appends them
     as a `PathRecord` to `repro.obs.default_solve_log()`, without a sync.
     """
+    from repro import dist
+    from repro.core.distributed import shard_rows
+    from repro.core.routing import route_path
+
     tracer = get_tracer()
     with tracer.span("enet_path.prepare"):
         X = jnp.asarray(X)
@@ -609,6 +624,13 @@ def enet_path(X, y, *, lambda1s=None, n_lambdas: int = 40,
         if lambda1s is None:
             lambda1s = lambda_grid(Xs, ys, n_lambdas=n_lambdas, eps=eps)
         lambda1s = jnp.asarray(lambda1s, X.dtype)
+        ctx = dist.current_context()
+        mesh = ctx[0] if ctx is not None else None
+        layout = route_path(*Xs.shape, Xs.dtype.itemsize, mesh)
+        _layout_counter().inc(layout=layout)
+        if layout == "rows":
+            with tracer.span("enet_path.place"):
+                Xs, ys = shard_rows(mesh, Xs, ys)
         config = resolve_path_config(config, Xs, ys)
     with tracer.span("enet_path.dispatch"):
         pts = _enet_path_scan(Xs, ys, lambda1s, jnp.asarray(lambda2, X.dtype),
@@ -623,6 +645,15 @@ def enet_path(X, y, *, lambda1s=None, n_lambdas: int = 40,
                     n_kept=pts.n_kept, evals=pts.evals,
                     sven_iters=pts.sven_iters, cg_steps=pts.cg_steps,
                     stop=pts.stop)
+
+
+def _layout_counter():
+    """``enet_path_layout_total{layout="single"|"rows"}``: `enet_path`
+    calls by the layout `core.routing.route_path` chose."""
+    from repro.obs.metrics import default_registry
+    return default_registry().counter(
+        "enet_path_layout_total", "enet_path calls by row layout",
+        ("layout",))
 
 
 class ElasticNet:

@@ -232,7 +232,6 @@ def make_distributed_hessian_matvec(mesh: Mesh, X: jax.Array, y: jax.Array,
 #                                  on the assembled (2p, 2p) kernel.
 #     primal Xhat @ w              one psum of (p + 1) floats per product
 #            Xhat^T v              one all-gather of an n-vector
-#            hinge stats           one psum of (p + 2) floats
 #
 # Rows pad with ZEROS to a multiple of the mesh size — a zero sample with a
 # zero response adds nothing to the Elastic Net objective, to any Gram
@@ -304,38 +303,6 @@ def sharded_gram_stats(mesh: Mesh, X: jax.Array, y: jax.Array, t) -> jax.Array:
 
     G, u, s = sharded_stats(X, y, t, mesh=mesh)
     return red.gram_from_stats(G, u, s)
-
-
-def sharded_hinge_stats(mesh: Mesh, X: jax.Array, y: jax.Array, t,
-                        w: jax.Array, C):
-    """`kernels.ref.hinge_stats_ref` on a row-sharded X: the fused Newton
-    outer-step stats (margin, act, loss, galpha) from ONE psum of p + 2
-    floats — X_loc^T w_loc, y_loc . w_loc and w_loc . w_loc.
-
-    Standalone fused form, parity-tested against the jnp oracle; the
-    primal solver machine (`_sven_sharded_primal`) composes its
-    matvec/rmatvec closures instead, so this op serves stats-driven outer
-    loops and diagnostics rather than the solve hot path."""
-    from repro.kernels.ref import hinge_stats_from_moments
-
-    axes = _flat_axes(mesh)
-    p = X.shape[1]
-    dtype = X.dtype
-
-    def local(X_loc, y_loc, t_op, C_op, w_full):
-        n_loc = X_loc.shape[0]
-        rank = jax.lax.axis_index(axes)
-        w_loc = jax.lax.dynamic_slice_in_dim(w_full, rank * n_loc, n_loc)
-        stats = jax.lax.psum(jnp.concatenate([
-            X_loc.T @ w_loc, (y_loc @ w_loc)[None], (w_loc @ w_loc)[None]]),
-            axes)
-        return hinge_stats_from_moments(stats[:p], stats[p] / t_op,
-                                        stats[p + 1], C_op)
-
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(axes, None), P(axes), P(), P(), P()),
-                     out_specs=(P(), P(), P(), P()), check_vma=False)(
-                         X, y, jnp.asarray(t, dtype), jnp.asarray(C, dtype), w)
 
 
 def _sven_sharded_primal(mesh: Mesh, X, y, t, C, warm_w, config):

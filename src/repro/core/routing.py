@@ -412,6 +412,39 @@ def route_solve(n: int, p: int, *, mesh: Optional[Mesh] = None,
     return _DECISIONS[key]
 
 
+#: Bytes the penalized path's one-chip program plans per byte of an f64 X:
+#: TPU v5e emulates f64, and the compiler's memory analysis of
+#: `api._enet_path_scan` (10 points, arguments + outputs + temporaries)
+#: reads 22.66 GB at 463,716 x 90, 67.9 times X's 334 MB, mostly X split
+#: into f32 pieces for each pass (PERF.md). Dtypes the chip runs natively
+#: plan less, so for them the estimate errs towards the row layout.
+PATH_PLAN_X_BYTES = 68
+
+
+def chip_memory_bytes(device) -> Optional[int]:
+    """The device's memory for one program (`bytes_limit`), or None where
+    the platform reports none (the host CPU)."""
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
+def route_path(n: int, p: int, itemsize: int, mesh: Optional[Mesh]) -> str:
+    """Layout of an `enet_path` over an (n, p) design: "rows" or "single".
+
+    Only in a mesh context (`mesh` given, more than one device), and only
+    for a tall design (2p <= n, the dual regime): there each pass over X
+    splits over the chips' rows and each reduction over rows ends in an
+    all-reduce of at most p^2 floats. A wide design (2p > n) has few rows
+    to split. A tall design takes rows when its one-chip plan
+    (`PATH_PLAN_X_BYTES` per byte of X) does not fit one chip's memory;
+    where it fits, one chip runs it with no collectives.
+    """
+    if mesh is None or mesh.size <= 1 or 2 * p > n:
+        return "single"
+    limit = chip_memory_bytes(mesh.devices.flat[0])
+    plan = PATH_PLAN_X_BYTES * n * p * itemsize
+    return "rows" if limit is not None and plan > limit else "single"
+
+
 def route_batch(n: int, p: int, batch_size: int, mesh: Optional[Mesh] = None,
                 *, form: str = "constrained", points: int = 1,
                 route: str = "auto") -> RouteDecision:

@@ -59,26 +59,6 @@ def hessian_matvec_ref(X, y, t, C, act_top, act_bot, v):
     return hinge_xd_ref(X, y, d, e, v, t, C)
 
 
-def hinge_stats_from_moments(a: jax.Array, byw, ww, C):
-    """The margin/act/loss/galpha tail of the hinge-stats fusion, from the
-    sufficient moments a = X^T w (p,), byw = (y . w) / t and ww = w . w.
-
-    Shared by the full oracle below and by the data-parallel twin
-    (`core.distributed.sharded_hinge_stats`, which psums the moments over
-    row shards first) so the formula has exactly one home.
-    """
-    p = a.shape[0]
-    dtype = a.dtype
-    o = jnp.concatenate([a - byw, a + byw])
-    margin = jnp.concatenate([o[:p], -o[p:]])
-    act = (margin < 1.0).astype(dtype)
-    xi = act * (1.0 - margin)
-    loss = 0.5 * ww + C * (xi @ xi)
-    yhat = jnp.concatenate([jnp.ones((p,), dtype), -jnp.ones((p,), dtype)])
-    galpha = act * (o - yhat)
-    return margin, act, loss, galpha
-
-
 def hinge_stats_ref(X: jax.Array, y: jax.Array, t: float, w: jax.Array, C: float):
     """Oracle for the fused margins/loss/gradient kernel (Newton outer step).
 
@@ -94,4 +74,14 @@ def hinge_stats_ref(X: jax.Array, y: jax.Array, t: float, w: jax.Array, C: float
     Returns (margin, act, loss, galpha).
     """
     a = (X.T @ w).astype(w.dtype)
-    return hinge_stats_from_moments(a, (y @ w) / t, w @ w, C)
+    byw = (y @ w) / t
+    p = a.shape[0]
+    dtype = a.dtype
+    o = jnp.concatenate([a - byw, a + byw])
+    margin = jnp.concatenate([o[:p], -o[p:]])
+    act = (margin < 1.0).astype(dtype)
+    xi = act * (1.0 - margin)
+    loss = 0.5 * (w @ w) + C * (xi @ xi)
+    yhat = jnp.concatenate([jnp.ones((p,), dtype), -jnp.ones((p,), dtype)])
+    galpha = act * (o - yhat)
+    return margin, act, loss, galpha
