@@ -191,6 +191,7 @@ class EnetPoint(NamedTuple):
     evals: jax.Array      # Illinois iterations spent (== SVEN solves)
     sven_iters: jax.Array # total inner solver iterations across evals
     cg_steps: jax.Array   # CG iterations across evals (0 with FISTA: no CG)
+    stalls: jax.Array     # evals whose primal solve ended on its stall stop
     stop: jax.Array       # int8 root-find stop cause, one of the STOP_* codes
 
 
@@ -225,6 +226,7 @@ class _Illinois(NamedTuple):
     evals: jax.Array
     iters: jax.Array
     cg: jax.Array         # CG iterations summed over the evaluations
+    stalls: jax.Array     # evaluations ended on the primal's stall stop
     restarted: jax.Array  # bool: the bracket was re-opened cold once
 
 
@@ -322,6 +324,7 @@ def _enet_point(X: jax.Array, y: jax.Array, lambda1, lambda2,
         evals=jnp.zeros((), jnp.int32),
         iters=jnp.zeros((), jnp.int32),
         cg=jnp.zeros((), jnp.int32),
+        stalls=jnp.zeros((), jnp.int32),
         restarted=jnp.zeros((), bool),
     )
 
@@ -367,7 +370,9 @@ def _enet_point(X: jax.Array, y: jax.Array, lambda1, lambda2,
         return _Illinois(t_lo, f_lo, t_hi, f_hi, side, arrs.beta, arrs.alpha,
                          arrs.w, nu_c, f_c, s.evals + 1,
                          s.iters + arrs.iters.astype(jnp.int32),
-                         s.cg + arrs.cg_steps.astype(jnp.int32), s.restarted)
+                         s.cg + arrs.cg_steps.astype(jnp.int32),
+                         s.stalls + arrs.stalled.astype(jnp.int32),
+                         s.restarted)
 
     s = jax.lax.while_loop(cond, body, state0)
     stop = jnp.where(~has_root, STOP_NO_ROOT,
@@ -386,7 +391,7 @@ def _enet_point(X: jax.Array, y: jax.Array, lambda1, lambda2,
     point = EnetPoint(beta=beta, t=t_out, nu=nu_out, kkt=kkt,
                       keep=keep, n_kept=jnp.sum(keep), gap=gap,
                       evals=s.evals, sven_iters=s.iters, cg_steps=s.cg,
-                      stop=stop)
+                      stalls=s.stalls, stop=stop)
     return next_carry, point
 
 
@@ -541,6 +546,7 @@ class EnetResult(NamedTuple):
     evals: jax.Array       # SVEN solves spent on the multiplier root-find
     sven_iters: jax.Array
     cg_steps: jax.Array    # CG iterations (0 with the FISTA solver)
+    stalls: jax.Array      # evals ended on the primal's stall stop
     stop: jax.Array        # root-find stop code (STOP_*)
 
 
@@ -556,6 +562,7 @@ class EnetPath(NamedTuple):
     evals: jax.Array       # (L,) SVEN solves per point
     sven_iters: jax.Array  # (L,)
     cg_steps: jax.Array    # (L,) CG iterations (0 with the FISTA solver)
+    stalls: jax.Array      # (L,) evals ended on the primal's stall stop
     stop: jax.Array        # (L,) int8 root-find stop codes (STOP_*)
 
 
@@ -580,7 +587,7 @@ def enet(X, y, lambda1, lambda2, *, standardize: bool = False,
                       lambda2=float(lambda2), t=pt.t, nu=pt.nu,
                       n_kept=pt.n_kept, evals=pt.evals,
                       sven_iters=pt.sven_iters, cg_steps=pt.cg_steps,
-                      stop=pt.stop)
+                      stalls=pt.stalls, stop=pt.stop)
 
 
 def enet_path(X, y, *, lambda1s=None, n_lambdas: int = 40,
@@ -608,8 +615,10 @@ def enet_path(X, y, *, lambda1s=None, n_lambdas: int = 40,
 
     Besides the coefficients, every point reports its root-find
     evaluations, inner iterations, CG steps (0 with the FISTA solver, which
-    runs no CG) and root-find stop code (`STOP_*`); each call appends them
-    as a `PathRecord` to `repro.obs.default_solve_log()`, without a sync.
+    runs no CG), the evaluations whose primal solve ended on its stall stop
+    (`core.svm.primal_newton`; 0 in the dual) and root-find stop code
+    (`STOP_*`); each call appends them as a `PathRecord` to
+    `repro.obs.default_solve_log()`, without a sync.
     """
     from repro import dist
     from repro.core.distributed import shard_rows
@@ -637,14 +646,15 @@ def enet_path(X, y, *, lambda1s=None, n_lambdas: int = 40,
                               config)
     default_solve_log().add(PathRecord(evals=pts.evals,
                                        sven_iters=pts.sven_iters,
-                                       cg_steps=pts.cg_steps, stop=pts.stop))
+                                       cg_steps=pts.cg_steps,
+                                       stalls=pts.stalls, stop=pts.stop))
     with tracer.span("enet_path.unscale"):
         betas, intercepts = unscale_coef(pts.beta, scaler)
     return EnetPath(lambda1s=lambda1s, lambda2=float(lambda2), betas=betas,
                     intercepts=intercepts, ts=pts.t, nus=pts.nu, kkts=pts.kkt,
                     n_kept=pts.n_kept, evals=pts.evals,
                     sven_iters=pts.sven_iters, cg_steps=pts.cg_steps,
-                    stop=pts.stop)
+                    stalls=pts.stalls, stop=pts.stop)
 
 
 def _layout_counter():

@@ -338,11 +338,12 @@ def _sven_sharded_primal(mesh: Mesh, X, y, t, C, warm_w, config):
                                   cg_iters=config.cg_iters, w0=w0)
         alpha = C_op * jnp.maximum(1.0 - yhat * matvec(res.w), 0.0)
         beta = red.recover_beta(alpha, t_op)
-        return beta, alpha, res.w, res.iters, res.grad_norm, res.cg_steps
+        return (beta, alpha, res.w, res.iters, res.grad_norm, res.cg_steps,
+                res.stalled)
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axes, None), P(axes), P(), P(), P()),
-                     out_specs=(P(),) * 6, check_vma=False)(
+                     out_specs=(P(),) * 7, check_vma=False)(
                          X, y, jnp.asarray(t, dtype), jnp.asarray(C, dtype),
                          warm_w)
 
@@ -392,7 +393,7 @@ def _sven_sharded_dual_jit(stats, K, X, y, t, lambda2, warm_alpha, *,
     kkt = en.kkt_violation(X, y, beta, lambda2)
     return SvenArrays(beta=beta, alpha=res.alpha, w=w[:n_orig],
                       iters=res.iters, opt_residual=res.pg_norm, kkt=kkt,
-                      cg_steps=cg_steps)
+                      cg_steps=cg_steps, stalled=jnp.zeros((), bool))
 
 
 @partial(jax.jit, static_argnames=("mesh", "n_orig", "config"))
@@ -405,13 +406,14 @@ def _sven_sharded_primal_jit(X, y, t, lambda2, warm_w, *, mesh: Mesh,
     _bump_trace("sven_sharded")
     dtype = X.dtype
     C = red.svm_C(lambda2, floor=config.lambda2_floor).astype(dtype)
-    beta, alpha, w, iters, opt, cg_steps = _sven_sharded_primal(
+    beta, alpha, w, iters, opt, cg_steps, stalled = _sven_sharded_primal(
         mesh, X, y, t, C, warm_w, config)
     # KKT diagnostics on the (padded == original) problem; rows stay sharded
     # under the partitioner, one all-reduce for the X^T r contraction.
     kkt = en.kkt_violation(X, y, beta, lambda2)
     return SvenArrays(beta=beta, alpha=alpha, w=w[:n_orig], iters=iters,
-                      opt_residual=opt, kkt=kkt, cg_steps=cg_steps)
+                      opt_residual=opt, kkt=kkt, cg_steps=cg_steps,
+                      stalled=stalled)
 
 
 def sven_sharded(X: jax.Array, y: jax.Array, t, lambda2, config=None, *,

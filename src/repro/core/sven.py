@@ -111,6 +111,7 @@ class SvenArrays(NamedTuple):
     opt_residual: jax.Array
     kkt: jax.Array
     cg_steps: jax.Array       # CG iterations over all Newton steps (0: FISTA)
+    stalled: jax.Array        # bool: primal stall stop (dual: False)
 
 
 class SvenSolution(NamedTuple):
@@ -282,7 +283,7 @@ def _sven_core(
         return SvenArrays(beta=beta, alpha=alpha, w=res.w, iters=res.iters,
                           opt_residual=res.grad_norm,
                           kkt=en.kkt_violation(X_full, y, beta, lambda2),
-                          cg_steps=res.cg_steps)
+                          cg_steps=res.cg_steps, stalled=res.stalled)
 
     # --- dual ---
     m = 2 * p
@@ -328,7 +329,7 @@ def _sven_core(
     return SvenArrays(beta=beta, alpha=res.alpha, w=w, iters=res.iters,
                       opt_residual=res.pg_norm,
                       kkt=en.kkt_violation(X_full, y, beta, lambda2),
-                      cg_steps=cg_steps)
+                      cg_steps=cg_steps, stalled=jnp.zeros((), bool))
 
 
 @partial(jax.jit, static_argnames=("config",))

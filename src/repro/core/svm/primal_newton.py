@@ -29,6 +29,19 @@ The machine's `aux` is the int32 count of CG iterations spent so far
 (`PrimalResult.cg_steps`): `_cg` returns the iteration count its loop
 already carries, and each Newton step adds it.
 
+A solve stops when max |grad| <= tol, or on the stall stop: a Newton step
+whose line search had to halve (s < 1) while the predicted decrease
+gd = grad . d was at most `STALL_RTOL` * max(|f|, 1) is the solve's last
+step. It is still applied. At that size gd is rounding: the Armijo test
+then compares f values that differ by noise alone, the search halves until
+noise lets a step through, w moves by rounding, and every further step
+repeats the search from the same w until `max_newton`. So the stop returns
+the answer the cap would have returned, to rounding, without those steps.
+A step taken at s = 1 never stalls, so the last full steps near the
+optimum still run to the gradient test. `PrimalResult.stalled` records a
+stall stop apart from the tolerance: the machine's `converged` is the stop
+flag, and `grad_norm` stays above `tol` on a stalled solve.
+
 The solver is a `SolverState` init/step/run machine (state.py, DESIGN.md
 §6): hyperparameters (C, tol) are traced scalars, the carry is fixed-shape,
 and everything is jax.lax control flow — so one trace serves a whole
@@ -47,12 +60,23 @@ from repro.core.svm.state import (Hyper, SolverMachine, SolverState,
                                   initial_state, make_hyper, run_machine)
 
 
+#: The stall stop's threshold on gd, a share of |f| (module docstring). On
+#: GLI-85-shaped paths in f64, stalled steps read gd <= 4e-15 |f| on a CPU
+#: and up to 5e-9 |f| on a TPU v5e, whose emulated f64 gradient carries
+#: more rounding; productive halving steps read gd >= 3e-3 |f| on both
+#: (PERF.md). A multiple of eps(dtype) wide enough for the TPU would
+#: exceed |f| in float32 and stop every halving step there; 1e-7 is below
+#: one float32 rounding of f, where float32 solves stall.
+STALL_RTOL = 1e-7
+
+
 class PrimalResult(NamedTuple):
     w: jax.Array
     iters: jax.Array
     grad_norm: jax.Array
     objective: jax.Array
     cg_steps: jax.Array     # CG iterations summed over the Newton steps
+    stalled: jax.Array      # bool: ended on the stall stop, not on tol
 
 
 def _cg(matvec: Callable, b: jax.Array, maxiter: int, tol):
@@ -172,11 +196,13 @@ def primal_newton_machine(
             ls_cond, ls_body, (jnp.asarray(1.0, dtype),
                                f_line(jnp.asarray(1.0, dtype))))
         gnorm = jnp.max(jnp.abs(grad))
+        stalled = (s < 1.0) & (gd <= STALL_RTOL
+                               * jnp.maximum(jnp.abs(f0), 1.0))
         # ~(> tol) rather than (<= tol): a NaN residual counts as terminal,
         # so a diverged solve exits instead of spinning to max_iters.
         return SolverState(x=w - s * dstep, aux=state.aux + cg_it,
-                           iters=state.iters + 1,
-                           residual=gnorm, converged=~(gnorm > hyper.tol))
+                           iters=state.iters + 1, residual=gnorm,
+                           converged=~(gnorm > hyper.tol) | stalled)
 
     def run(hyper: Hyper, x0: jax.Array | None = None) -> SolverState:
         return run_machine(step, init(hyper, x0), hyper, max_newton)
@@ -208,4 +234,6 @@ def solve_primal_newton(
     st = machine.run(hyper, w0)
     return PrimalResult(w=st.x, iters=st.iters, grad_norm=st.residual,
                         objective=_primal_obj(matvec, yhat, st.x, hyper.C),
-                        cg_steps=st.aux)
+                        cg_steps=st.aux,
+                        # the stop flag raised with the gradient above tol
+                        stalled=st.converged & (st.residual > hyper.tol))

@@ -15,7 +15,8 @@ with one common carry:
 `x` is the solver's iterate (primal w or dual alpha), `aux` holds any
 solver-private fixed-shape extras (FISTA momentum; the Newton solvers'
 running count of CG iterations), `residual` is the
-solver's own optimality measure and `converged` its tolerance flag. Because
+solver's own optimality measure and `converged` its stop flag (the
+tolerance reached, or the primal Newton's stall stop). Because
 the carry is a fixed-shape pytree and the hyperparameters (`Hyper.C`,
 `Hyper.tol`) enter as *traced scalars* — never Python floats baked into the
 trace — a machine composes directly with `jax.jit`, `jax.lax.scan`
@@ -45,7 +46,7 @@ class SolverState(NamedTuple):
     aux: Any              # solver-private extras (fixed-shape pytree)
     iters: jax.Array      # int32 outer-iteration count
     residual: jax.Array   # solver's optimality measure (sup-norm)
-    converged: jax.Array  # bool: residual <= tol reached
+    converged: jax.Array  # bool: residual <= tol reached, or a stall stop
 
     def telemetry(self) -> dict:
         """Host-side scalar summary of where the solve ended (DESIGN.md

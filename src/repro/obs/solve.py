@@ -18,13 +18,13 @@ loop.
 
 Every `core.api.enet_path` call also appends one `PathRecord` to the
 process-wide `default_solve_log()`: its per-point root-find evaluations,
-inner solver iterations, CG steps and root-find stop causes, as the device
-arrays the call returned. Recording never syncs; the arrays come to the
-host only when the log is read. This is where an operator finds
-uncertified points: `SolveLog.summary()` counts the points by stop
-cause, and any under "bracket" or "max_evals" ended with |nu - lambda1|
-above the root-find's tolerance (`core.api.STOP_*`); `path_records()`
-says which call and which point.
+inner solver iterations, CG steps, primal stall stops and root-find stop
+causes, as the device arrays the call returned. Recording never syncs; the
+arrays come to the host only when the log is read. This is where an
+operator finds uncertified points: `SolveLog.summary()` counts the points
+by stop cause, and any under "bracket" or "max_evals" ended with
+|nu - lambda1| above the root-find's tolerance (`core.api.STOP_*`);
+`path_records()` says which call and which point.
 """
 from __future__ import annotations
 
@@ -64,6 +64,7 @@ class PathRecord(NamedTuple):
     evals: Any        # root-find evaluations (SVEN solves)
     sven_iters: Any   # inner solver iterations
     cg_steps: Any     # CG iterations
+    stalls: Any       # evaluations ended on the primal's stall stop
     stop: Any         # root-find stop code, indexes STOP_CAUSES
 
     def host(self) -> "PathRecord":
@@ -95,15 +96,21 @@ class SolveLog:
 
     def summary(self) -> dict:
         """Points of the retained path calls by stop cause, and CG steps
-        per point."""
+        and stall stops per point."""
         recs = self.path_records()
-        stop = np.concatenate([r.stop for r in recs]) if recs else np.zeros(0)
-        cg = np.concatenate([r.cg_steps for r in recs]) if recs else np.zeros(0)
+
+        def per_point(field):
+            return (np.concatenate([getattr(r, field) for r in recs]) if recs
+                    else np.zeros(0))
+
+        stop, cg, stalls = (per_point(f) for f in ("stop", "cg_steps", "stalls"))
         return {"paths": len(recs), "points": int(stop.size),
                 "by_stop": {name: int(np.sum(stop == code))
                             for code, name in enumerate(STOP_CAUSES)},
                 "cg_steps_per_point": (float(np.mean(cg)) if cg.size
-                                       else None)}
+                                       else None),
+                "stalls_per_point": (float(np.mean(stalls)) if stalls.size
+                                     else None)}
 
     def residual_report(self) -> dict:
         """Modeled-vs-actual summary per route path.

@@ -218,17 +218,54 @@ def test_path_log_summary_matches_the_paths():
     recs = log.path_records()
     assert len(recs) == 2 and log.records() == []
     for rec, path in zip(recs, paths):
-        for field in ("evals", "sven_iters", "cg_steps", "stop"):
+        for field in ("evals", "sven_iters", "cg_steps", "stalls", "stop"):
             np.testing.assert_array_equal(getattr(rec, field),
                                           np.asarray(getattr(path, field)))
     stop = np.concatenate([np.asarray(p.stop) for p in paths])
     cg = np.concatenate([np.asarray(p.cg_steps) for p in paths])
+    stalls = np.concatenate([np.asarray(p.stalls) for p in paths])
     summary = log.summary()
     assert summary["paths"] == 2 and summary["points"] == 9
     assert summary["by_stop"] == {
         name: int(np.sum(stop == code)) for code, name in enumerate(STOP_CAUSES)}
     assert summary["cg_steps_per_point"] == pytest.approx(float(np.mean(cg)))
+    assert summary["stalls_per_point"] == pytest.approx(
+        float(np.mean(stalls)))
     assert log.residual_report()["n_records"] == 0
+    log.clear()
+
+
+@pytest.fixture
+def fresh_traces():
+    """Clears JAX's trace caches around a test that patches a constant
+    read at trace time, so that no test shares its executables."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("mode", ["primal", "dual"])
+def test_stalls_reach_the_point_the_path_and_the_log(mode, monkeypatch,
+                                                     fresh_traces):
+    """With every halving line search counted a stall, the primal's stall
+    stops reach `EnetPoint`, `EnetResult`, `EnetPath` and the path log's
+    `stalls_per_point`; the dual Newton never stalls."""
+    monkeypatch.setattr(primal_newton, "STALL_RTOL", np.inf)
+    n, p = (20, 40) if mode == "primal" else (60, 8)
+    X, y, _ = make_regression(n, p, k_true=4, seed=11)
+    lmax = float(api.en.lambda1_max(X, y))
+    lam1s = jnp.asarray([0.5, 0.2, 0.05]) * lmax
+    log = default_solve_log()
+    log.clear()
+    path = enet_path(X, y, lambda1s=lam1s)
+    assert log.summary()["stalls_per_point"] == pytest.approx(
+        float(np.mean(path.stalls)))
+    # enet_batch's points and enet's result each solve from a cold start
+    for res in (path, enet_batch(X, y, lam1s, 1.0),
+                enet(X, y, float(lam1s[2]), 1.0)):
+        stalls = np.asarray(res.stalls)
+        assert np.all((0 <= stalls) & (stalls <= np.asarray(res.evals)))
+        assert (int(stalls.sum()) > 0) == (mode == "primal")
     log.clear()
 
 
