@@ -51,7 +51,7 @@ def main():
           f"kkt_violation={float(sol.kkt):.2e}")
 
     # the same solve through the Pallas kernel backend (interpret mode on CPU)
-    sol_k = sven(X, y, t, lam2, SvenConfig(backend="pallas", tol=1e-6))
+    sol_k = sven(X, y, t, lam2, SvenConfig(backend="auto", tol=1e-6))
     print(f"pallas backend agreement: {float(jnp.abs(sol_k.beta - sol.beta).max()):.2e}")
 
 

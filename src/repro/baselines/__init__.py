@@ -1,5 +1,4 @@
 from repro.baselines.coordinate_descent import elastic_net_cd
 from repro.baselines.fista import elastic_net_fista
-from repro.baselines.shotgun import elastic_net_shotgun
 
-__all__ = ["elastic_net_cd", "elastic_net_fista", "elastic_net_shotgun"]
+__all__ = ["elastic_net_cd", "elastic_net_fista"]

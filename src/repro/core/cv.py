@@ -36,7 +36,7 @@ def _auto_fold_chunk(k: int, mesh) -> int:
     A vmapped `while_loop` costs the MAX trip count across lanes at every
     nesting level (Illinois evals x Newton iters x CG), so on a single CPU
     device the k-wide lockstep runs ~1.6x SLOWER than solving folds one
-    after another (BENCH_path.json's cv section tracks this). chunk=1 keeps
+    after another (measured on a CPU host). chunk=1 keeps
     everything inside ONE executable — an outer `lax.scan` over folds, no
     per-fold dispatch — which is what beats the host-side per-fold loop on
     CPU.

@@ -1,8 +1,8 @@
 """Adaptive execution routing: which device layout should run this solve?
 
 PR 5 made the row-sharded `sven_sharded` path available everywhere a mesh
-was in scope — and BENCH_path.json promptly recorded the cost of using it
-unconditionally: a lone (768, 48) solve ran 10x SLOWER sharded than on one
+was in scope — and a CPU-host benchmark promptly recorded the cost of using
+it unconditionally: a lone (768, 48) solve ran 10x SLOWER sharded than on one
 device (`dist_solve.solve_speedup = 0.10`), because every collective pays
 mesh latency and the replicated Newton solve competes with its own shards
 for the simulated host devices' shared cores. The paper's claim is "as fast
@@ -306,7 +306,7 @@ def _solve_costs(n: int, p: int, mode: str, cal: Calibration) -> dict:
     """Predicted seconds for one solve under each layout."""
     F = cal.flops_per_s
     # the dual data pass runs on the RESOLVED kernel backend (Pallas Gram
-    # on tpu/gpu, XLA GEMM otherwise) — price it at that kernel's measured
+    # on tpu, XLA GEMM otherwise) — price it at that kernel's measured
     # rate, not the generic GEMM rate
     G = cal.gram_flops_per_s or F
     data, iters = _solve_flops(n, p, mode)

@@ -46,11 +46,12 @@ import jax.numpy as jnp
 from repro.core import elastic_net as en
 from repro.core import reduction as red
 from repro.core.svm import solve_dual_fista, solve_dual_newton, solve_primal_newton
+from repro.kernels import registry
 
 # ---------------------------------------------------------------------------
 # Trace instrumentation: each jit-wrapped entry point bumps its counter ONCE
-# per trace (the bump runs at trace time, not at execution time). Tests and
-# benchmarks assert e.g. a 40-point path costs exactly one trace. The counts
+# per trace (the bump runs at trace time, not at execution time). Tests
+# assert e.g. a 40-point path costs exactly one trace. The counts
 # live on the process-wide obs registry (``solver_traces_total{entry=...}``,
 # DESIGN.md §12.2) so they export beside router decisions; a `trace:<entry>`
 # instant marks WHEN each (re)trace happened on the timeline — a nonzero
@@ -124,14 +125,11 @@ class SvenSolution(NamedTuple):
     w: jax.Array              # primal SVM iterate — warm-start carrier
 
 
-#: every accepted SvenConfig.backend spelling: "xla" = no kernels module at
+#: every accepted SvenConfig.backend value: "xla" = no kernels module at
 #: all (pure-jnp matrix-free reduction); "auto" = kernel registry, body
-#: resolved from the operands' platform; "pallas" = deprecated alias of
-#: "auto" (the pre-enum spelling); the rest are RESOLVED kernel backends
-#: (kernels/registry.py: body + execution mode).
-BACKENDS = ("xla", "auto", "pallas",
-            "tpu", "gpu", "tpu_interpret", "gpu_interpret", "ref")
-PRECISIONS = ("f32", "bf16", "tf32")
+#: resolved from the operands' platform; the rest are RESOLVED kernel
+#: backends (kernels/registry.py: body + execution mode).
+BACKENDS = ("xla", "auto") + registry.RESOLVED_BACKENDS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,15 +139,9 @@ class SvenConfig:
     cache_kernel: str = "auto"    # "auto" | "blocks" | "never" (dual only)
     solver: str = "newton"        # "newton" | "fista" (dual only)
     backend: str = "xla"          # one of BACKENDS (DESIGN.md §10)
-    # DEPRECATED two-flag-era Pallas interpret switch. None = unresolved:
-    # `resolve_backend` folds any explicit value into the backend enum
-    # (backend "auto" + interpret=True -> "<body>_interpret") and
-    # normalizes this field back to None so equivalent spellings hash to
-    # the SAME jit key. New code should pass a resolved backend instead.
-    interpret: Optional[bool] = None
-    # kernel MAC/storage precision: "f32" | "bf16" | "tf32". Applies to the
+    # kernel MAC/storage precision: "f32" | "bf16". Applies to the
     # registry-backed kernel paths only ("xla" and the ref oracle always
-    # compute at full input precision); low-precision dual solves get one
+    # compute at full input precision); bf16 dual solves get one
     # full-precision iterative-refinement re-solve (DESIGN.md §10.3) so the
     # <= 1e-10 parity gates still hold.
     precision: str = "f32"
@@ -163,9 +155,10 @@ class SvenConfig:
         if self.backend not in BACKENDS:
             raise ValueError(f"SvenConfig.backend must be one of {BACKENDS}, "
                              f"got {self.backend!r}")
-        if self.precision not in PRECISIONS:
+        if self.precision not in registry.PRECISIONS:
             raise ValueError(f"SvenConfig.precision must be one of "
-                             f"{PRECISIONS}, got {self.precision!r}")
+                             f"{registry.PRECISIONS}, got "
+                             f"{self.precision!r}")
 
 
 def _pick_mode(n: int, p: int, cfg: SvenConfig) -> str:
@@ -181,31 +174,17 @@ def resolve_backend(config: SvenConfig, *arrays) -> SvenConfig:
     input arrays are committed to (`kernels.registry.resolve_kernel_backend`
     — never the process default backend at trace time, DESIGN.md §9.3), so
     the compiled executable matches where the data actually lives; two
-    placements that need different kernel bodies get different jit keys.
-
-    The deprecated spellings fold in here: backend "pallas" is an alias of
-    "auto", and an explicit `interpret=` flag is pushed into the backend
-    value ("<body>_interpret") and then normalized to None — so e.g.
-    `SvenConfig(backend="pallas")` and `SvenConfig(backend="pallas",
-    interpret=True)` resolve to the SAME config (same jit key) on CPU. A
-    no-op (same object) for the "xla" backend and for already-resolved
-    configs, which `api.resolve_path_config` relies on.
+    placements that need different kernel bodies get different jit keys,
+    and "auto" and the backend it resolves to share one. A no-op (same
+    object) for the "xla" backend and for already-resolved configs, which
+    `api.resolve_path_config` relies on.
     """
     if config.backend == "xla":
-        if config.interpret is None:
-            return config
-        return dataclasses.replace(config, interpret=None)
-    from repro.kernels import registry
-
-    resolved = registry.resolve_kernel_backend(
-        None if config.backend in ("auto", "pallas") else config.backend,
-        *arrays)
-    if config.interpret is not None and resolved != "ref":
-        body, _ = registry.split_backend(resolved)
-        resolved = body + ("_interpret" if config.interpret else "")
-    if resolved == config.backend and config.interpret is None:
         return config
-    return dataclasses.replace(config, backend=resolved, interpret=None)
+    resolved = registry.resolve_kernel_backend(config.backend, *arrays)
+    if resolved == config.backend:
+        return config
+    return dataclasses.replace(config, backend=resolved)
 
 
 def _sven_core(
@@ -311,7 +290,7 @@ def _sven_core(
     res = solver(kernel_matvec, m, C, dtype=dtype, tol=config.tol, alpha0=warm_alpha)
     cg_steps = res.cg_steps
     if refine:
-        # one step of iterative refinement (DESIGN.md §10.3): the bf16/tf32
+        # one step of iterative refinement (DESIGN.md §10.3): the bf16
         # kernel bought the O(np^2) Gram pass cheap; re-solving MATRIX-FREE
         # at full input precision, warm-started from the low-precision
         # alpha, re-evaluates every Newton residual against exact Gram

@@ -1,39 +1,45 @@
-"""Pallas kernels for the SVEN hot spots (TPU + GPU/Triton bodies), with
-pure-jnp oracles and a per-backend registry.
+"""Pallas TPU kernels for the SVEN hot spots, with pure-jnp oracles and a
+per-backend registry.
 
 Public surface:
 
   - `ops` — the jitted entry points (`shifted_gram`, `hinge_hessian_matvec`,
-    `hinge_stats`): backend resolution, tiling/padding/precision handling,
-    and the deprecated `use_pallas=`/`interpret=` shims;
+    `hinge_stats`): backend resolution and tiling/padding/precision
+    handling;
   - `registry` — the op -> body table and the `backend` enum
     (`resolve_kernel_backend`, `lookup`, `kernel_backends`);
   - `autotune` — per-(body, shape-bucket) tile selection with an on-disk
     winner cache (`tiles_for`, `resolve_tiles`);
   - `ref` — the pure-jnp oracles, the correctness ground truth every kernel
     is parity-tested against (`tests/test_kernels.py`,
-    `tests/test_kernels_surface.py`, `tests/test_kernels_gpu.py`).
+    `tests/test_kernels_surface.py`).
 
-The ops are re-exported at package level; `core/sven.py` selects them via
-`SvenConfig(backend=...)`. Raw kernel bodies (`gram`, `gram_gpu`, `hinge`,
-`hinge_stats`, `hinge_stats_gpu` modules) are implementation detail — call
-through `ops`, which owns backend lookup, tiling and padding.
+The ops are re-exported at package level and load on first use: only
+`registry`, the backend and precision names `core/sven.py` validates
+against, loads with the package. `core/sven.py` selects the ops via
+`SvenConfig(backend=...)`. Raw kernel bodies (`gram`, `hinge`,
+`hinge_stats` modules) are implementation detail — call through `ops`,
+which owns backend lookup, tiling and padding.
 """
-from repro.kernels import autotune, ops, ref, registry
-from repro.kernels.ops import (hinge_hessian_matvec, hinge_stats,
-                               resolve_interpret, sharded_shifted_gram,
-                               shifted_gram)
+import importlib
+
+from repro.kernels import registry
 from repro.kernels.registry import resolve_kernel_backend
 
-__all__ = [
-    "ops",
-    "ref",
-    "registry",
-    "autotune",
-    "shifted_gram",
-    "sharded_shifted_gram",
-    "hinge_hessian_matvec",
-    "hinge_stats",
-    "resolve_interpret",
-    "resolve_kernel_backend",
-]
+_OPS = ("shifted_gram", "sharded_shifted_gram", "hinge_hessian_matvec",
+        "hinge_stats", "resolve_interpret")
+
+__all__ = ["ops", "ref", "registry", "autotune", *_OPS,
+           "resolve_kernel_backend"]
+
+
+def __getattr__(name: str):
+    # The first use of anything but `registry` imports `ops`, which imports
+    # and registers every body. Pallas comes with it (about 1.7 s), which a
+    # process that only names the backend enum never pays.
+    ops = importlib.import_module(f"{__name__}.ops")
+    # the ops shadow same-named submodules (`hinge_stats`)
+    globals().update((fn, getattr(ops, fn)) for fn in _OPS)
+    if name in globals():
+        return globals()[name]
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -4,7 +4,7 @@ Tile shapes (bm, bn, bk) that saturate one accelerator generation are
 mediocre on the next; hardcoded defaults are how a "GPU-speed" claim decays.
 This module picks tiles the same way GPU-SVM practice does (Rgtsvm tunes
 its kernel-evaluation tile to the card): measure a few candidates ONCE per
-(body, shape-bucket) on the hardware at hand and reuse the winner.
+(body, shape-bucket) on the chip at hand and reuse the winner.
 
 Mechanics:
 
@@ -12,7 +12,7 @@ Mechanics:
     problem is tuned on a bounded probe) — tiles depend on how a problem
     fills the machine, not its exact dims, and buckets keep the candidate
     sweep from re-running per shape;
-  * measurement happens only on COMPILED backends ("tpu", "gpu"). Interpret
+  * measurement happens only on the COMPILED backend ("tpu"). Interpret
     mode is a pure-Python emulator whose timings are pathological and
     meaningless, and the ref body has no tiles — both get the static
     defaults instantly;
@@ -36,18 +36,15 @@ from repro import utils
 from repro.kernels import registry
 
 # candidates per body — orderings chosen so the FIRST entry is the static
-# default used whenever measurement is unavailable. GPU tiles respect
-# Triton's >= 16 tl.dot dimension floor; TPU tiles are MXU/VREG multiples.
+# default used whenever measurement is unavailable. TPU tiles are MXU/VREG
+# multiples.
 GRAM_CANDIDATES = {
     "tpu": ((128, 128, 128), (256, 128, 128), (128, 128, 256),
             (128, 256, 128)),
-    "gpu": ((64, 64, 32), (32, 32, 32), (64, 64, 64), (128, 64, 32),
-            (128, 128, 32)),
     "ref": ((128, 128, 128),),
 }
 HINGE_STATS_CANDIDATES = {
     "tpu": ((512, 512), (256, 512), (512, 256), (256, 256)),
-    "gpu": ((64, 128), (32, 128), (64, 256), (128, 128)),
     "ref": ((512, 512),),
 }
 _TILE_NAMES = {"shifted_gram": ("bm", "bn", "bk"),
@@ -84,13 +81,11 @@ def _key(op: str, body: str, nb: int, pb: int, dtype) -> str:
     return f"{op}|{body}|{nb}x{pb}|{jnp.dtype(dtype).name}|jax{jax.__version__}"
 
 
-def _clamp(tiles: tuple, op: str, nb: int, pb: int, body: str) -> tuple:
-    """Shrink candidate tiles that exceed the bucket (tiny problems); the
-    GPU gram body keeps >= 16 so tl.dot stays legal."""
-    floor = 16 if (body == "gpu" and op == "shifted_gram") else 8
+def _clamp(tiles: tuple, op: str, nb: int, pb: int) -> tuple:
+    """Shrink candidate tiles that exceed the bucket (tiny problems)."""
     names = _TILE_NAMES[op]
     dims = {"bm": pb, "bn": pb, "bp": pb, "bk": nb}
-    return tuple(max(min(t, _pow2(dims[nm])), floor)
+    return tuple(max(min(t, _pow2(dims[nm])), 8)
                  for t, nm in zip(tiles, names))
 
 
@@ -127,10 +122,10 @@ def resolve_tiles(op: str, backend: str, n: int, p: int,
     if op not in _CANDIDATES:
         raise ValueError(f"resolve_tiles: unknown op {op!r} "
                          f"(expected one of {sorted(_CANDIDATES)})")
-    cands = _CANDIDATES[op].get(body, _CANDIDATES[op]["ref"])
+    cands = _CANDIDATES[op][body]
     nb, pb = shape_bucket(n, p)
     names = _TILE_NAMES[op]
-    default = dict(zip(names, _clamp(cands[0], op, nb, pb, body)))
+    default = dict(zip(names, _clamp(cands[0], op, nb, pb)))
     if interpret or body == "ref" or len(cands) == 1:
         return default, "default"
 
@@ -148,7 +143,7 @@ def resolve_tiles(op: str, backend: str, n: int, p: int,
     seen: dict[tuple, float] = {}
     errors: dict[tuple, Exception] = {}
     for cand in cands:
-        tiles = _clamp(cand, op, nb, pb, body)
+        tiles = _clamp(cand, op, nb, pb)
         if tiles in seen or tiles in errors:
             continue
         try:

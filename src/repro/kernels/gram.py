@@ -43,7 +43,7 @@ def _gram_kernel(xi_ref, xj_ref, y_ref, invt_ref, out_ref,
     xj = xj_ref[...].astype(jnp.float32)          # (bk, bn)
     yk = y_ref[...].astype(jnp.float32)           # (bk, 1)
 
-    # "f32" forces full-precision MACs; "bf16"/"tf32" allow the MXU's fast
+    # "f32" forces full-precision MACs; "bf16" allows the MXU's fast
     # low-precision passes — accumulation stays f32 either way, and one f32
     # refinement re-solve on top restores <= 1e-10 parity (DESIGN.md §10.3).
     prec = (jax.lax.Precision.HIGHEST if precision == "f32"

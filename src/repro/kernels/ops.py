@@ -1,21 +1,20 @@
 """Jitted public wrappers for the kernel bodies: backend resolution through
 the per-backend registry (kernels/registry.py), tile selection through the
-autotuner (kernels/autotune.py), padding, dtype/precision handling, and the
-deprecation shims that keep the old `use_pallas`/`interpret` flags working.
+autotuner (kernels/autotune.py), padding, and dtype/precision handling.
 
 One `backend` enum drives everything (DESIGN.md §10): a resolved value from
-`registry.RESOLVED_BACKENDS` names both the kernel body ("tpu" Pallas,
-"gpu" Pallas/Triton, "ref" jnp oracle) and how it executes (compiled vs
-interpret). `None`/"auto" resolves from the OPERANDS' committed devices,
-never from the process default backend at trace time (the §9.3 bugfix);
+`registry.RESOLVED_BACKENDS` names both the kernel body ("tpu" Pallas, "ref"
+jnp oracle) and how it executes (compiled vs interpret). `None`/"auto"
+resolves from the OPERANDS' committed devices, never from the process
+default backend at trace time (the §9.3 bugfix);
 traced callers (`core/sven.py`, the bucket executables) thread an explicit
 resolved value from `SvenConfig.backend`, pinned pre-trace by
 `core.sven.resolve_backend`.
 
-Precision (`"f32" | "bf16" | "tf32"`) selects the MAC path of the Gram
-kernel and the storage dtype fed to the fused stats kernels; accumulation
-is f32 in every cell of the matrix (README "Backends & precision"). The
-"ref" body ignores it — the oracle always computes at full input precision.
+Precision (`"f32" | "bf16"`) selects the MAC path of the Gram kernel and
+the storage dtype fed to the fused stats kernels; accumulation is f32 in
+every cell of the matrix (README "Backends & precision"). The "ref" body
+ignores it — the oracle always computes at full input precision.
 
 Tiles: explicit `bm=`/`bn=`/`bk=`/`bp=` kwargs always win; unset tiles come
 from `autotune.tiles_for`, which measures candidates once per (body,
@@ -23,7 +22,6 @@ shape-bucket) on compiled backends and uses static defaults elsewhere.
 """
 from __future__ import annotations
 
-import warnings
 from functools import partial
 from typing import Optional
 
@@ -32,16 +30,12 @@ import jax.numpy as jnp
 
 from repro.kernels import autotune, registry
 from repro.kernels import gram as _gram
-from repro.kernels import gram_gpu as _gram_gpu          # registers gpu body
 from repro.kernels import hinge as _hinge
 from repro.kernels import hinge_stats as _hs
-from repro.kernels import hinge_stats_gpu as _hs_gpu     # registers gpu body
 from repro.kernels import ref as _ref
 
-PRECISIONS = ("f32", "bf16", "tf32")
-
-# bodies not defined with a @register decorator wire up here, once, at
-# import time (re-import just overwrites the same keys)
+# every body wires up here, once, at import time (re-import just
+# overwrites the same keys)
 registry.register("shifted_gram", "tpu")(_gram.gram_pallas_raw)
 registry.register("shifted_gram", "ref")(_ref.gram_blocks_ref)
 registry.register("hinge_stats", "tpu")(_hs.hinge_stats_raw)
@@ -54,30 +48,11 @@ registry.register("hinge_xd", "ref")(_ref.hinge_xd_ref)
 
 def resolve_interpret(interpret, *arrays) -> bool:
     """Deprecated two-flag-era helper: the interpret bit of the resolved
-    backend. Kept callable because DESIGN.md §9.3 and older call sites name
-    it; new code should use `registry.resolve_kernel_backend`."""
+    backend; new code should use `registry.resolve_kernel_backend`."""
     if interpret is not None:
         return bool(interpret)
     return registry.split_backend(
         registry.resolve_kernel_backend(None, *arrays))[1]
-
-
-def _resolve(backend: Optional[str], use_pallas, interpret, what: str,
-             *arrays) -> str:
-    """Fold the deprecated flags into one RESOLVED backend string."""
-    if use_pallas is not None or interpret is not None:
-        warnings.warn(
-            f"{what}: use_pallas=/interpret= are deprecated — pass "
-            f"backend= (one of {registry.RESOLVED_BACKENDS}, 'auto', or "
-            f"'ref' for the old use_pallas=False)", DeprecationWarning,
-            stacklevel=3)
-    if use_pallas is False:
-        return "ref"
-    resolved = registry.resolve_kernel_backend(backend, *arrays)
-    if interpret is not None and resolved != "ref":
-        body, _ = registry.split_backend(resolved)
-        resolved = body + ("_interpret" if interpret else "")
-    return resolved
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -99,15 +74,14 @@ def _next_mult(sz: int, base: int = 128) -> int:
 
 
 def _tile_partials(part: jax.Array) -> jax.Array:
-    """One scalar per grid tile from a kernel's per-tile partials: (G, 1)
-    from the Triton bodies, (G, 8, 128) lane-aligned tiles from the TPU
-    bodies (every element of a tile holds the same value)."""
+    """One scalar per grid tile from a kernel's (G, 8, 128) lane-aligned
+    per-tile partials (every element of a tile holds the same value)."""
     return part.reshape(part.shape[0], -1)[:, 0]
 
 
 def _storage(Xp: jax.Array, precision: str) -> jax.Array:
     """bf16 keeps reduced-precision STORAGE (the Rgtsvm recipe — kernels
-    accumulate f32 regardless); f32/tf32 leave the operand alone."""
+    accumulate f32 regardless); f32 leaves the operand alone."""
     return Xp.astype(jnp.bfloat16) if precision == "bf16" else Xp
 
 
@@ -137,17 +111,14 @@ def shifted_gram(
     flatten: bool = True,
     backend: Optional[str] = None,
     precision: str = "f32",
-    use_pallas: Optional[bool] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """K = Zhat^T Zhat of the SVEN dual, as (2p, 2p) (flatten) or (2,2,p,p).
 
     `backend=None`/"auto" resolves against X's committed devices (see
     `registry.resolve_kernel_backend`); traced call sites must pass an
-    explicit resolved value. `use_pallas=`/`interpret=` are the deprecated
-    two-flag spelling.
+    explicit resolved value.
     """
-    resolved = _resolve(backend, use_pallas, interpret, "shifted_gram", X, y)
+    resolved = registry.resolve_kernel_backend(backend, X, y)
     tiles = _gram_tiles(resolved, *X.shape, bm, bn, bk, precision)
     return _shifted_gram_jit(X, y, t, flatten=flatten, backend=resolved,
                              precision=precision, **tiles)
@@ -197,17 +168,9 @@ def hinge_hessian_matvec(
     bk: int = 512,
     backend: Optional[str] = None,
     precision: str = "f32",
-    use_pallas: Optional[bool] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """H v = v + 2C Xhat^T(act . (Xhat v)) via two fused GEMV passes.
-
-    Only the TPU body exists — the op is GEMV-shaped and memory-bound, so
-    on GPU the registry serves the "ref" oracle (XLA/cuBLAS is the honest
-    choice there; see README "Backends & precision").
-    """
-    resolved = _resolve(backend, use_pallas, interpret,
-                        "hinge_hessian_matvec", X, v)
+    """H v = v + 2C Xhat^T(act . (Xhat v)) via two fused GEMV passes."""
+    resolved = registry.resolve_kernel_backend(backend, X, v)
     return _hinge_hessian_matvec_jit(
         X, y, t, C, act_top, act_bot, v, bp=bp, bn=bn, bk=bk,
         backend=resolved, precision=precision)
@@ -273,15 +236,12 @@ def hinge_stats(
     bk: Optional[int] = None,
     backend: Optional[str] = None,
     precision: str = "f32",
-    use_pallas: Optional[bool] = None,
-    interpret: Optional[bool] = None,
 ):
     """Fused Newton outer-step stats: (margin (2p,), act (2p,), loss, galpha).
 
-    Served by the TPU body, the GPU (Triton) body, or the ref oracle per
-    the resolved backend; the deprecated flags shim as in `shifted_gram`.
+    Served by the TPU body or the ref oracle per the resolved backend.
     """
-    resolved = _resolve(backend, use_pallas, interpret, "hinge_stats", X, w)
+    resolved = registry.resolve_kernel_backend(backend, X, w)
     if bp is None or bk is None:
         tiles = autotune.tiles_for("hinge_stats", resolved, *X.shape)
         bp = bp if bp is not None else tiles["bp"]
@@ -345,8 +305,6 @@ def sharded_shifted_gram(
     bk: Optional[int] = None,
     backend: Optional[str] = None,
     precision: str = "f32",
-    use_pallas: Optional[bool] = None,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """K = Zhat^T Zhat with the ROWS of X sharded over `mesh` (DESIGN.md §9).
 
@@ -362,8 +320,7 @@ def sharded_shifted_gram(
     from jax.sharding import PartitionSpec as P
 
     axes = tuple(mesh.axis_names)
-    resolved = _resolve(backend, use_pallas, interpret,
-                        "sharded_shifted_gram", X, y)
+    resolved = registry.resolve_kernel_backend(backend, X, y)
     n_loc = X.shape[0] // mesh.size
     tiles = _gram_tiles(resolved, n_loc, X.shape[1], bm, bn, bk, precision)
 

@@ -1,38 +1,31 @@
-"""Per-backend kernel registry: one `backend` enum replaces the old
-`use_pallas: bool` + `interpret: bool` two-flag maze (DESIGN.md §10).
+"""Per-backend kernel registry: one `backend` enum names the kernel body
+and how it executes (DESIGN.md §10).
 
 Every logical op (`shifted_gram`, `hinge_stats`, `hinge_xtv`/`hinge_xd`,
-`sharded_shifted_gram`) resolves to exactly one of three BODIES:
+`sharded_shifted_gram`) resolves to exactly one of two BODIES:
 
     "tpu"   the Pallas TPU kernel (kernels/gram.py, hinge.py, hinge_stats.py)
-    "gpu"   the Pallas GPU (Triton) kernel (kernels/gram_gpu.py,
-            hinge_stats_gpu.py) — k-loop inside the program, no TPU scratch
     "ref"   the pure-jnp oracle (kernels/ref.py) — also the XLA escape hatch
 
 and a RESOLVED backend names a body plus how it executes:
 
-    "tpu" | "gpu"                      compiled Pallas for that platform
-    "tpu_interpret" | "gpu_interpret"  the same body under Pallas interpret
-                                       mode (how CPU CI exercises both code
-                                       paths without an accelerator)
-    "ref"                              the jnp oracle under plain XLA
+    "tpu"            compiled Pallas for the TPU
+    "tpu_interpret"  the same body under Pallas interpret mode (how the CPU
+                     test suite exercises it without an accelerator)
+    "ref"            the jnp oracle under plain XLA
 
 Resolution is OPERAND-DRIVEN, never trace-time backend sniffing (the §9.3
 bugfix): `resolve_kernel_backend(None, *arrays)` reads the platform of the
-first concrete operand's committed devices — tpu -> "tpu", gpu -> "gpu",
-cpu -> "tpu_interpret" (the historical CPU default) — with the process
-default backend only as the numpy/tracer fallback. An explicit resolved
-backend always wins. Traced call sites thread `SvenConfig.backend`, pinned
+first concrete operand's committed devices — tpu -> "tpu", cpu ->
+"tpu_interpret", any other platform -> "ref" — with the process default
+backend only as the numpy/tracer fallback. An explicit resolved backend
+always wins. Traced call sites thread `SvenConfig.backend`, pinned
 pre-trace by `core.sven.resolve_backend`, so the choice is part of the
 static jit key.
 
-`lookup` serves the "ref" oracle in place of a missing body only for the
-pairs in `REF_FALLBACKS`, the README "Backends & precision" matrix: the
-hinge Hessian mat-vec has no Triton body (GEMV-shaped, memory-bound;
-cuBLAS under XLA is the honest choice), so "gpu" serves it from the
-oracle. Any other missing body raises — in particular no op resolved to
-"tpu" silently runs the oracle. `kernel_backends(op)` reports what is
-actually registered.
+`lookup` never substitutes one body for another: a missing body raises, so
+no op resolved to "tpu" silently runs the oracle. `kernel_backends(op)`
+reports what is actually registered.
 """
 from __future__ import annotations
 
@@ -40,23 +33,20 @@ from typing import Callable, Optional
 
 import jax
 
-#: the three kernel bodies a logical op may register
-BODIES = ("tpu", "gpu", "ref")
+#: the kernel bodies a logical op may register
+BODIES = ("tpu", "ref")
 
 #: every resolved backend value accepted by the ops layer / SvenConfig
-RESOLVED_BACKENDS = ("tpu", "gpu", "tpu_interpret", "gpu_interpret", "ref")
+RESOLVED_BACKENDS = ("tpu", "tpu_interpret", "ref")
 
-#: platform -> resolved backend (the "auto" rule)
+#: kernel MAC/storage precisions accepted by the ops layer / SvenConfig
+PRECISIONS = ("f32", "bf16")
+
+#: platform -> resolved backend (the "auto" rule; other platforms -> "ref")
 _PLATFORM_DEFAULT = {
     "tpu": "tpu",
-    "gpu": "gpu",
-    "cuda": "gpu",
-    "rocm": "gpu",
     "cpu": "tpu_interpret",
 }
-
-#: (op, body) pairs the README matrix serves from the "ref" oracle
-REF_FALLBACKS = frozenset({("hinge_xtv", "gpu"), ("hinge_xd", "gpu")})
 
 _REGISTRY: dict[tuple[str, str], Callable] = {}
 
@@ -74,12 +64,8 @@ def register(op: str, body: str):
 
 
 def lookup(op: str, backend: str) -> tuple[Callable, str, bool]:
-    """Resolve (impl, body, interpret) for a RESOLVED backend.
-
-    Falls back to the "ref" body only where `REF_FALLBACKS` (the README
-    "Backends & precision" matrix) says the platform has no kernel for this
-    op; any other missing body is a KeyError.
-    """
+    """Resolve (impl, body, interpret) for a RESOLVED backend; a missing
+    body is a KeyError."""
     if backend not in RESOLVED_BACKENDS:
         raise ValueError(
             f"lookup({op!r}): backend must be resolved "
@@ -88,8 +74,6 @@ def lookup(op: str, backend: str) -> tuple[Callable, str, bool]:
     body, interpret = split_backend(backend)
     if (op, body) in _REGISTRY:
         return _REGISTRY[(op, body)], body, interpret
-    if (op, body) in REF_FALLBACKS and (op, "ref") in _REGISTRY:
-        return _REGISTRY[(op, "ref")], "ref", False
     raise KeyError(f"no kernel body registered for op {op!r} "
                    f"(backend {backend!r}); registered: {kernel_backends(op)}")
 
@@ -111,18 +95,17 @@ def registered_ops() -> tuple[str, ...]:
 
 
 def resolve_kernel_backend(backend: Optional[str], *arrays) -> str:
-    """Pin the kernel backend for a launch (the one-enum successor of
-    `resolve_interpret`).
+    """Pin the kernel backend for a launch.
 
-    An explicit RESOLVED backend always wins. `None` / `"auto"` / the
-    deprecated `"pallas"` resolve from the platform(s) the first CONCRETE
-    array operand is committed to — the devices the kernel will actually
-    run on — not from the process default backend (wrong for arrays placed
-    on a non-default device, meaningless inside a trace). Tracers and
+    An explicit RESOLVED backend always wins. `None` / `"auto"` resolve
+    from the platform(s) the first CONCRETE array operand is committed to —
+    the devices the kernel will actually run on — not from the process
+    default backend (wrong for arrays placed on a non-default device,
+    meaningless inside a trace). Tracers and
     numpy inputs carry no device, so the process default platform remains
     the last-resort fallback only.
     """
-    if backend is not None and backend not in ("auto", "pallas"):
+    if backend is not None and backend != "auto":
         if backend not in RESOLVED_BACKENDS:
             raise ValueError(
                 f"resolve_kernel_backend: unknown backend {backend!r} "

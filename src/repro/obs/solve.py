@@ -10,9 +10,8 @@ modeled price is `core.routing.estimate_batch_seconds` taken AT DISPATCH
 dispatch -> harvest wall time with the blocking wait broken out.
 
 `SolveLog.residual_report()` folds the records into the cost-model
-residual summary serialized into BENCH_path.json's ``obs`` section: per
-route path, the distribution of log10(actual/modeled). A drifting residual
-is the signal to re-run `core.routing.calibrate(force=True)` — this is the
+residual summary: per route path, the distribution of
+log10(actual/modeled). A drifting residual is the signal to re-run `core.routing.calibrate(force=True)` — this is the
 data needed to validate and later recalibrate the router, closing the PR 6
 loop.
 

@@ -5,9 +5,7 @@ A `Tracer` records nested spans on the host's monotonic ns clock into a
 bounded deque — no device syncs, no allocation beyond one tuple per span,
 and a disabled tracer costs one attribute check and one profiler
 annotation per span site, so the instrumentation can stay in the serving
-and solver hot paths permanently (the
-telemetry-overhead gate in ``benchmarks/bench_obs.py`` holds enabled
-tracing to <= 1.10x disabled p99).
+and solver hot paths permanently.
 
 Span taxonomy (DESIGN.md §12.1 — the names CI schema-checks for):
 

@@ -13,7 +13,7 @@ NEAREST in log-space, provided it falls inside the `neighborhood` radius.
 The hit is fed back into `sven_batch` / `enet_batch` as a warm start — never
 returned directly — so a hit changes iteration count, not the answer:
 repeat and adjacent-lambda traffic re-solves in a few Newton steps instead
-of from cold (measured in BENCH_path.json's ``serve.cache_hit_rate``).
+of from cold (the scheduler's `cache.hit_rate`).
 
 Stored warm arrays live in the PADDED bucket geometry the scheduler solves
 in (a fingerprint maps to one bucket, since buckets are shape-derived), so

@@ -6,9 +6,8 @@ launched (its micro-batch dispatched to the device) and completed (results
 unpadded and delivered) — so the recorder can split end-to-end latency into
 queue wait (submitted -> launched: the price of coalescing) and service
 time (launched -> completed: device compute + harvest). `summary()` folds
-the rolled-up state into the percentile/throughput numbers
-`benchmarks/bench_serve.py` serializes into BENCH_path.json's ``serve``
-section.
+the rolled-up state into the percentile/throughput numbers the loadgen
+(`runtime/loadgen.py`) reports.
 
 Memory is BOUNDED: only OPEN (not-yet-completed) requests keep a
 per-request record; completion folds the record into exponential-bucket

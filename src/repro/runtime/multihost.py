@@ -283,8 +283,8 @@ class MultiHostCoordinator:
                           for hid, reg in sorted(self.host_registries.items())}}
 
     def accounting(self) -> dict:
-        """The no-silent-drops invariant as numbers (bench_obs gates it):
-        every admitted request must sit in exactly one terminal-status
+        """The no-silent-drops invariant as numbers (the fault tests gate
+        it): every admitted request must sit in exactly one terminal-status
         counter once traffic has drained."""
         terminals = {status: int(v) for (status,), v
                      in self._terminal.series().items()}

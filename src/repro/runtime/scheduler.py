@@ -289,7 +289,7 @@ class ContinuousScheduler:
         self.stats = RuntimeStats(self.registry)
         self.metrics = LatencyRecorder(registry=self.registry)
         # every admitted request must end in exactly ONE terminal status —
-        # the accounting invariant bench_obs gates fleet-wide
+        # the accounting invariant the fault tests gate fleet-wide
         self._terminal = self.registry.counter(
             "requests_terminal_total",
             "admitted requests by terminal status", ("status",))

@@ -1,10 +1,9 @@
-from repro.serve.engine import (ElasticNetEngine, EngineStats, EnResult,
+from repro.serve.engine import (ElasticNetEngine, EnResult,
                                 greedy_generate, make_decode_step,
                                 make_prefill_step)
 
 __all__ = [
     "ElasticNetEngine",
-    "EngineStats",
     "EnResult",
     "make_decode_step",
     "make_prefill_step",

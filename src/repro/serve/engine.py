@@ -26,8 +26,7 @@ asynchronously (overlapping with each other) with warm starts from the
 scheduler's solution cache, and results are awaited only at harvest.
 `drain_reference()` keeps the seed engine's synchronous path — one
 blocking, cold `sven_batch`/`enet_batch` call per bucket chunk — as the
-parity oracle the runtime is tested and benchmarked against
-(`benchmarks/bench_serve.py`).
+parity oracle the runtime is tested against (`tests/test_runtime.py`).
 """
 from __future__ import annotations
 
@@ -43,9 +42,6 @@ from repro.models import model as M
 from repro.runtime.cache import PENALIZED, SolutionCache
 from repro.runtime.scheduler import (ContinuousScheduler, EnResult,
                                      RuntimeStats, ceil_pow2, stack_padded)
-
-#: Back-compat alias: the engine's stats ARE the runtime scheduler's.
-EngineStats = RuntimeStats
 
 
 def make_prefill_step(cfg: M.ModelConfig, max_len: int):
@@ -207,8 +203,7 @@ class ElasticNetEngine:
 
         Kept as the parity oracle for the runtime path: `drain()` and
         `drain_reference()` return identical solutions to solver tolerance
-        (tested), and `benchmarks/bench_serve.py` measures the continuous
-        runtime's throughput against this baseline.
+        (`tests/test_runtime.py`).
         """
         queue = self._scheduler.take_pending()
         groups: dict = {}

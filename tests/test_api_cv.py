@@ -5,6 +5,7 @@ per-fold reference, keep-mask wiring, and the engine's penalized requests."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.baselines import elastic_net_cd
 from repro.baselines.coordinate_descent import cd_path
@@ -196,12 +197,17 @@ def test_sven_batch_keep_mask():
 # batched cross-validation
 # ---------------------------------------------------------------------------
 
-def test_cv_matches_sequential_reference_and_trace_budget():
+@pytest.mark.parametrize("n,p,k_true,rho,seed,n_lambdas", [
+    (84, 30, 6, 0.3, 5, 40),
+    (120, 32, 8, 0.4, 11, 8),
+])
+def test_cv_matches_sequential_reference_and_trace_budget(n, p, k_true, rho,
+                                                          seed, n_lambdas):
     """Acceptance: the batched CV surface equals the sequential per-fold loop,
     lambda selection agrees, the refit matches CD to 1e-5, and the whole
     screening-fused CV driver costs at most 2 traces (scan + refit)."""
-    X, y, _ = make_regression(84, 30, k_true=6, rho=0.3, seed=5)
-    kw = dict(k=4, n_lambdas=40, lambda2=1.0,
+    X, y, _ = make_regression(n, p, k_true=k_true, rho=rho, seed=seed)
+    kw = dict(k=4, n_lambdas=n_lambdas, lambda2=1.0,
               standardize=False, fit_intercept=False)
     reset_trace_counts()
     res = cross_validate(X, y, **kw)

@@ -47,20 +47,6 @@ def test_multihost_refuses_workers_on_tpu(monkeypatch):
         MultiHostCoordinator(n_hosts=2, start=False)
 
 
-@pytest.mark.parametrize("bench", ["bench_distributed", "bench_dist_solve"])
-def test_child_spawning_benches_refuse_on_tpu(monkeypatch, bench):
-    import importlib
-    mod = importlib.import_module(f"benchmarks.{bench}")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-    def no_child(*a, **k):
-        raise AssertionError("spawned a child on a TPU")
-
-    monkeypatch.setattr(subprocess, "run", no_child)
-    with pytest.raises(RuntimeError, match="belongs to one process"):
-        mod.run()
-
-
 def _smoke(script: Path, cwd: Path, env: dict):
     return subprocess.run([sys.executable, str(script)], cwd=cwd, env=env,
                           capture_output=True, text=True, timeout=300)

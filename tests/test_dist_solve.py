@@ -4,8 +4,8 @@ their bugfix satellites.
 Single-process tests cover the 1-device-mesh bitwise-parity contract of
 `sven_sharded` and `sven_routed`, the router's trivial/pinned semantics,
 the CV fold-chunk keying on RESOLVED placement (the nested-context
-regression), the explicit kernel backend/interpret threading (the
-`_on_cpu()` trace-time sniffing regression), the SolutionCache lambda-edge
+regression), the explicit kernel backend threading (the `_on_cpu()`
+trace-time sniffing regression), the SolutionCache lambda-edge
 keying (lasso-only / pure-ridge repeat traffic) and the lambda1 = 0
 screening guard. Real multi-device behavior — cross-device parity for
 sven / sven_routed / enet_path / CV at <= 1e-10, the routing decision
@@ -58,42 +58,34 @@ def test_resolve_interpret_from_committed_device():
 
 def test_resolve_backend_pins_enum_into_config():
     X, y, _ = make_regression(24, 10, seed=0)
-    cfg = SvenConfig(backend="pallas")      # deprecated alias of "auto"
-    assert cfg.interpret is None
-    resolved = resolve_backend(cfg, X, y)
+    resolved = resolve_backend(SvenConfig(backend="auto"), X, y)
     # CPU-committed operands -> the TPU body under interpret mode, as ONE
-    # resolved enum value; the legacy interpret field is normalized away
+    # resolved enum value, equal to the config that names it outright
     assert resolved.backend == "tpu_interpret"
-    assert resolved.interpret is None
-    assert resolve_backend(SvenConfig(backend="auto"), X, y) == resolved
-    # the deprecated interpret flag folds into the enum, not a second field
-    folded = resolve_backend(SvenConfig(backend="pallas", interpret=True),
-                             X, y)
-    assert folded == resolved
+    assert resolved == SvenConfig(backend="tpu_interpret")
     # xla configs are untouched (identity object: resolve_path_config
     # depends on the no-op returning the SAME config)
     plain = SvenConfig()
     assert resolve_backend(plain, X, y) is plain
     # already-resolved configs are identity too
-    pinned = SvenConfig(backend="gpu_interpret")
+    pinned = SvenConfig(backend="ref")
     assert resolve_backend(pinned, X, y) is pinned
 
 
 def test_sven_pallas_threading_no_retrace_and_parity():
-    """An unresolved pallas config and the explicitly-resolved one must hit
-    the SAME executable (the resolution happens before the jit key is
-    formed), and agree with the xla backend."""
+    """An "auto" config and the explicitly-resolved one must hit the SAME
+    executable (the resolution happens before the jit key is formed), and
+    agree with the xla backend."""
     X, y, _ = make_regression(96, 16, seed=1)  # dual regime (2p < n)
     X, y = X.astype(jnp.float64), y.astype(jnp.float64)
     base = sven(X, y, 1.1, 1.0)
     n0 = trace_counts().get("sven", 0)
-    s_auto = sven(X, y, 1.1, 1.0, SvenConfig(backend="pallas"))
+    s_auto = sven(X, y, 1.1, 1.0, SvenConfig(backend="auto"))
     n1 = trace_counts().get("sven", 0)
-    s_expl = sven(X, y, 1.1, 1.0, SvenConfig(backend="pallas",
-                                             interpret=True))
+    s_expl = sven(X, y, 1.1, 1.0, SvenConfig(backend="tpu_interpret"))
     n2 = trace_counts().get("sven", 0)
     assert n1 == n0 + 1
-    assert n2 == n1, "explicit interpret=True retraced: resolution did not " \
+    assert n2 == n1, "explicit tpu_interpret retraced: resolution did not " \
                      "pin the choice into the jit key"
     # pallas gram runs in f32; parity at f32 tolerance
     np.testing.assert_allclose(np.asarray(s_auto.beta), np.asarray(base.beta),
@@ -288,7 +280,7 @@ _PARITY_8DEV = textwrap.dedent("""
                       - sven(Xp, yp, 0.8, 0.7).beta).max())
     assert d <= TOL, f"primal sharded dev {d}"
     # pallas-backed sharded gram (interpret pinned outside the shard_map)
-    cfg = SvenConfig(backend="pallas")
+    cfg = SvenConfig(backend="auto")
     s3 = sven_sharded(X, y, 1.5, 1.0, cfg, mesh=mesh)
     d = float(jnp.abs(s3.beta - sven(X, y, 1.5, 1.0).beta).max())
     assert d <= 5e-5, f"pallas sharded dev {d}"     # f32 kernel
@@ -305,6 +297,11 @@ _PARITY_8DEV = textwrap.dedent("""
         sharded = sven_batch(Xb, yb, tb, l2b)
     d = float(jnp.abs(sharded.beta - plain.beta).max())
     assert d <= TOL, f"sven_batch sharded dev {d}"
+    # the fan-out pinned, where the router may decline it on host devices
+    with dist.mesh_context(mesh):
+        pinned = sven_batch(Xb, yb, tb, l2b, route="batch")
+    d = float(jnp.abs(pinned.beta - plain.beta).max())
+    assert d <= TOL, f"sven_batch pinned fan-out dev {d}"
     lam1 = jnp.linspace(0.8, 0.2, B)
     pl = enet_batch(Xb, yb, lam1, l2b)
     with dist.mesh_context(mesh):

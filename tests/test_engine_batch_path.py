@@ -141,11 +141,15 @@ def test_primal_machine_state_protocol():
 # sven_batch stacking patterns
 # ---------------------------------------------------------------------------
 
-def test_batch_grid_matches_sequential():
-    X, y, t_scale = _problem(60, 16, seed=7)
-    ts, l2s = en_grid(jnp.linspace(0.4, 1.2, 3) * t_scale, jnp.array([0.5, 1.0, 4.0]))
+@pytest.mark.parametrize("n,p,t_grid,lam2s,seed", [
+    (60, 16, (0.4, 1.2, 3), (0.5, 1.0, 4.0), 7),
+    (120, 24, (0.45, 1.5, 2), (0.5, 2.0), 3),
+])
+def test_batch_grid_matches_sequential(n, p, t_grid, lam2s, seed):
+    X, y, t_scale = _problem(n, p, seed=seed)
+    ts, l2s = en_grid(jnp.linspace(*t_grid) * t_scale, jnp.array(lam2s))
     sol = sven_batch(X, y, ts, l2s)
-    assert sol.beta.shape == (9, 16)
+    assert sol.beta.shape == (t_grid[2] * len(lam2s), p)
     for i in range(ts.shape[0]):
         ref = sven(X, y, float(ts[i]), float(l2s[i]))
         np.testing.assert_allclose(sol.beta[i], ref.beta, atol=PATH_ATOL)

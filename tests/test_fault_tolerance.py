@@ -80,12 +80,18 @@ def coordinator_factory():
 
 
 @pytest.mark.slow
-def test_kill_host_mid_drain_loses_nothing(coordinator_factory):
+@pytest.mark.parametrize("spill", [False, True])
+def test_kill_host_mid_drain_loses_nothing(coordinator_factory, tmp_path,
+                                           spill):
     """The headline contract: SIGKILL a worker while it holds dispatched
     batches; every admitted request must still complete "ok" (no deadlines
-    here, so the requeue path re-solves the dead host's work)."""
+    here, so the requeue path re-solves the dead host's work), with or
+    without a shared spill tier, at 1e-10 of a direct solve."""
+    from repro.core import sven
     X, y = _problem(0)
-    coord = coordinator_factory(n_hosts=2, max_batch=4)
+    coord = coordinator_factory(
+        n_hosts=2, max_batch=4,
+        cache_dir=str(tmp_path / "spill") if spill else None)
     ids = [coord.submit(X + 0.01 * k, y, t=1.0) for k in range(8)]
     coord.flush()                      # both hosts now hold in-flight work
     coord.kill_host(0)
@@ -94,8 +100,10 @@ def test_kill_host_mid_drain_loses_nothing(coordinator_factory):
     assert {r.status for r in out.values()} == {"ok"}
     assert coord.hosts_lost == 1
     assert coord.requeued_batches >= 1, "kill was not detected as a failure"
-    for r in out.values():             # re-solved results are real solutions
-        assert r.beta is not None and np.all(np.isfinite(np.asarray(r.beta)))
+    for k, rid in enumerate(ids):      # re-solved results are real solutions
+        direct = sven(X + 0.01 * k, y, 1.0, 1.0).beta
+        np.testing.assert_allclose(np.asarray(out[rid].beta),
+                                   np.asarray(direct), atol=1e-10)
 
 
 @pytest.mark.slow
@@ -145,6 +153,12 @@ def test_multihost_shared_spill_survives_host_loss(coordinator_factory,
     first = [coord.submit(X, y, t=0.8 + 0.05 * k) for k in range(8)]
     out = coord.drain()
     assert {out[r].status for r in first} == {"ok"}
+    # no fault yet: the fleet-merged worker counters saw exactly what the
+    # coordinator admitted, and its books balance
+    acct = coord.accounting()
+    assert acct["balanced"] and acct["admitted"] == 8
+    assert int(coord.fleet.counter("runtime_requests_total",
+                                   labelnames=()).total()) == 8
     coord.kill_host(0)                 # the half that solved some of wave 1
     again = [coord.submit(X, y, t=0.8 + 0.05 * k) for k in range(8)]
     out = coord.drain()

@@ -3,14 +3,11 @@ re-exports the ops/ref entry points, and the Pallas kernels agree with the
 pure-jnp oracles when forced through interpret mode — the explicit
 ref-vs-pallas parity contract for `hinge_hessian_matvec` and `shifted_gram`
 (test_kernels.py sweeps shapes/dtypes via the module paths; this file pins
-the package-level API and the interpret-mode escape hatches). The
-`use_pallas=`/`interpret=` spellings are the DEPRECATED two-flag era —
-kept here on purpose as the shim's behavioral contract (must warn, must
-still route to the same bodies as the `backend=` enum)."""
+the package-level API and the `backend=` enum's interpret-mode and "ref"
+escape hatches)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 import repro.kernels as kernels
 from repro.data.synthetic import make_regression
@@ -34,12 +31,10 @@ def test_public_surface_exports():
 def test_shifted_gram_pallas_interpret_matches_ref():
     X, y = _problem(72, 50, seed=1)
     t = 1.3
-    with pytest.warns(DeprecationWarning):
-        K_pallas = kernels.shifted_gram(X, y, t, bm=32, bn=32, bk=32,
-                                        use_pallas=True, interpret=True)
+    K_pallas = kernels.shifted_gram(X, y, t, bm=32, bn=32, bk=32,
+                                    backend="tpu_interpret")
     K_ref = kernels.ref.flatten_gram(kernels.ref.gram_blocks_ref(X, y, t))
-    with pytest.warns(DeprecationWarning):
-        K_escape = kernels.shifted_gram(X, y, t, use_pallas=False)
+    K_escape = kernels.shifted_gram(X, y, t, backend="ref")
     assert K_pallas.shape == (100, 100)
     scale = float(jnp.abs(K_ref).max())
     np.testing.assert_allclose(np.asarray(K_pallas), np.asarray(K_ref),
@@ -57,15 +52,12 @@ def test_hinge_hessian_matvec_pallas_interpret_matches_ref():
     at = (jax.random.uniform(jax.random.PRNGKey(4), (44,)) > 0.5).astype(
         jnp.float32)
     ab = 1.0 - at
-    with pytest.warns(DeprecationWarning):
-        hv_pallas = kernels.hinge_hessian_matvec(X, y, t, C, at, ab, v,
-                                                 bp=32, bn=32, bk=32,
-                                                 use_pallas=True,
-                                                 interpret=True)
+    hv_pallas = kernels.hinge_hessian_matvec(X, y, t, C, at, ab, v,
+                                             bp=32, bn=32, bk=32,
+                                             backend="tpu_interpret")
     hv_ref = kernels.ref.hessian_matvec_ref(X, y, t, C, at, ab, v)
-    with pytest.warns(DeprecationWarning):
-        hv_escape = kernels.hinge_hessian_matvec(X, y, t, C, at, ab, v,
-                                                 use_pallas=False)
+    hv_escape = kernels.hinge_hessian_matvec(X, y, t, C, at, ab, v,
+                                             backend="ref")
     scale = max(1.0, float(jnp.abs(hv_ref).max()))
     np.testing.assert_allclose(np.asarray(hv_pallas), np.asarray(hv_ref),
                                atol=1e-5 * scale)

@@ -187,7 +187,7 @@ class TestSYN001HostSync:
         def measure(x):
             return float(jnp.max(x))    # benchmarks harvest freely
         """
-        assert lint(ok, relpath="benchmarks/bench_x.py",
+        assert lint(ok, relpath="bench/jobs/x.py",
                     select=["SYN001"]).findings == []
 
 
@@ -559,7 +559,7 @@ class TestOutputAndExitCodes:
 
     def test_json_schema(self, tmp_path):
         report = tmp_path / "reprolint.json"
-        proc = self.run_cli("src", "benchmarks", "tools",
+        proc = self.run_cli("src", "tools",
                             "--format", "json", "--output", str(report))
         doc = json.loads(proc.stdout)
         assert doc == json.loads(report.read_text())
@@ -576,7 +576,7 @@ class TestOutputAndExitCodes:
                 "acceptance: every suppression carries a justification", s)
 
     def test_exit_zero_on_clean_tree_and_one_on_findings(self, tmp_path):
-        proc = self.run_cli("src", "benchmarks", "tools")
+        proc = self.run_cli("src", "tools")
         assert proc.returncode == 0, proc.stdout + proc.stderr
         bad = tmp_path / "bad.py"
         bad.write_text("import json\n"
@@ -613,7 +613,7 @@ class TestOutputAndExitCodes:
 # ---------------------------------------------------------------------------
 
 def test_whole_repo_lint_is_clean():
-    res = run_paths(REPO_ROOT, ["src", "benchmarks", "tools"],
+    res = run_paths(REPO_ROOT, ["src", "tools"],
                     load_config(REPO_ROOT))
     assert res.findings == [], "\n".join(f.render() for f in res.findings)
     # every live suppression carries its justification (SUP001 would have

@@ -13,8 +13,11 @@
   column padding through `submit_penalized` returns the exact unpadded
   `enet` solution — the penalized mirror of the constrained padding test;
 - online rank-1 updates == from-scratch solves on the accumulated rows;
-- metrics percentiles and loadgen reproducibility.
+- metrics percentiles and loadgen reproducibility; a repeated stream is
+  served warm, and with launches a pure function of the stream (no
+  deadlines) it adds no traces.
 """
+import dataclasses
 import time
 
 import jax.numpy as jnp
@@ -23,7 +26,7 @@ import pytest
 
 from _hypothesis_compat import given, settings, st
 
-from repro.core import enet, sven, sven_batch
+from repro.core import enet, sven, sven_batch, trace_counts
 from repro.core.api import EnetCarry, enet_batch
 from repro.core.elastic_net import lambda1_max
 from repro.data.synthetic import make_regression
@@ -434,27 +437,48 @@ def test_percentile_interpolation():
         percentile([], 50)
 
 
-def test_loadgen_reproducible_and_complete():
-    spec = LoadSpec(n_requests=10, n_datasets=2, penalized_fraction=0.3,
-                    shapes=((20, 10), (30, 14)), seed=3)
+LOADGEN_CASES = {
+    # partial buckets launch at their deadline
+    "deadline": (dict(n_requests=10, n_datasets=2, penalized_fraction=0.3,
+                      shapes=((20, 10), (30, 14)), seed=3),
+                 dict(max_batch=4, max_wait=0.002)),
+    # buckets launch when full or at the closing flush: the launches are a
+    # pure function of the stream
+    "full_buckets": (dict(n_requests=32, n_datasets=3,
+                          penalized_fraction=0.25, seed=7),
+                     dict(max_batch=8, max_wait=None)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOADGEN_CASES))
+def test_loadgen_reproducible_and_complete(case):
+    spec_kw, sched_kw = LOADGEN_CASES[case]
+    spec = LoadSpec(**spec_kw)
     w1, w2 = make_workload(spec), make_workload(spec)
     assert [i.lam for i in w1] == [i.lam for i in w2]
     assert all((a.X == b.X).all() for a, b in zip(w1, w2))
     # data_seed pins datasets while the lambda stream moves
-    w3 = make_workload(LoadSpec(n_requests=10, n_datasets=2,
-                                penalized_fraction=0.3,
-                                shapes=((20, 10), (30, 14)), seed=4,
-                                data_seed=3))
+    w3 = make_workload(dataclasses.replace(spec, seed=spec.seed + 1,
+                                           data_seed=spec.seed))
     fp1 = {fingerprint_problem(i.X, i.y) for i in w1}
     fp3 = {fingerprint_problem(i.X, i.y) for i in w3}
     assert fp3 <= fp1 and [i.lam for i in w3] != [i.lam for i in w1]
 
-    sched = ContinuousScheduler(max_batch=4, max_wait=0.002)
+    sched = ContinuousScheduler(**sched_kw)
     out = run_open_loop(sched, w1)
-    assert out["n_completed"] == 10 and len(out["results"]) == 10
+    n = spec.n_requests
+    assert out["n_completed"] == n and len(out["results"]) == n
     assert out["p99_latency_s"] >= out["p50_latency_s"] > 0
     for item, rid in zip(w1, out["ids"]):
         ref = (enet(item.X, item.y, item.lam, item.lambda2).beta
                if item.form == "penalized"
                else sven(item.X, item.y, item.lam, item.lambda2).beta)
         np.testing.assert_allclose(out["results"][rid].beta, ref, atol=ATOL)
+
+    # the same stream again: the warm-start cache serves it and, where the
+    # launches do not depend on the clock, no entry point retraces
+    traces, hits = trace_counts(), sched.cache.hits
+    again = run_open_loop(sched, w1)
+    assert again["n_completed"] == n and sched.cache.hits > hits
+    if sched_kw["max_wait"] is None:
+        assert trace_counts() == traces

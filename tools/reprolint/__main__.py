@@ -12,7 +12,7 @@ from .config import LintConfig, load_config
 from .engine import render_json, render_text, run_paths
 from .registry import all_rules
 
-DEFAULT_PATHS = ["src", "benchmarks", "tools"]
+DEFAULT_PATHS = ["src", "tools"]
 
 
 def main(argv=None) -> int:

@@ -2,7 +2,7 @@
 
 The measured trap: a collective per loop iteration. A partitioner-sharded
 vmapped while_loop all-reduces EVERY iteration — ~60x slower than the
-fan-out that keeps lanes independent (PR 5, BENCH_path.json dist_solve).
+fan-out that keeps lanes independent (PR 5, measured on a CPU host mesh).
 The audited counterexamples (CG with one psum per matvec in
 core/distributed.py, the pipeline's per-tick ppermute) are excluded by
 pyproject scoping or carry inline justifications."""

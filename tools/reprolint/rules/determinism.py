@@ -1,6 +1,6 @@
 """Determinism rules: every random stream in the library is seeded and
 injectable. Global-RNG draws make solver parity runs (the <=1e-10 gates in
-validate_artifact) unreproducible, and time-seeded RNGs make CI flakes
+the tests) unreproducible, and time-seeded RNGs make CI flakes
 undiagnosable."""
 from __future__ import annotations
 
@@ -27,7 +27,7 @@ class GlobalRng(Rule):
     """DET001: draws from the process-global RNG.
 
     `np.random.rand(...)`-style legacy calls and stdlib `random.*` share
-    hidden global state across tests/benchmarks; the repo idiom is a
+    hidden global state across tests and runs; the repo idiom is a
     seeded `np.random.default_rng(seed)` (or `jax.random.key`) passed down
     explicitly.
     """
@@ -35,7 +35,7 @@ class GlobalRng(Rule):
     meta = RuleMeta(
         id="DET001", name="global-rng",
         summary="no process-global RNG draws (np.random legacy / random.*)",
-        default_include=("src", "benchmarks"))
+        default_include=("src",))
 
     def check(self, ctx):
         for call in ctx.calls():
@@ -66,7 +66,7 @@ class UnseededRng(Rule):
     meta = RuleMeta(
         id="DET002", name="unseeded-rng",
         summary="RNGs take explicit, non-clock seeds",
-        default_include=("src", "benchmarks"))
+        default_include=("src",))
 
     def check(self, ctx):
         for call in ctx.calls():

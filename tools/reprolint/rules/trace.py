@@ -24,7 +24,7 @@ class ImportTimeDeviceWork(Rule):
     meta = RuleMeta(
         id="TRC001", name="import-time-jnp",
         summary="no jax.numpy/device work at module import time",
-        default_include=("src", "benchmarks"))
+        default_include=("src",))
 
     def check(self, ctx):
         for node in self._import_time_nodes(ctx.tree):
